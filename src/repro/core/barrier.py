@@ -64,12 +64,10 @@ class TwoPhaseBarrier:
         with self._lock:
             t0 = time.perf_counter()
             # Phase 1a: the in-flight operation completes (paper: worker
-            # drains its current job and blocks).
+            # drains its current job and blocks).  Host-only leaves pass
+            # through unchanged; a device error raised here propagates.
             if inflight is not None:
-                try:
-                    jax.block_until_ready(inflight)
-                except Exception:
-                    pass  # host-only values have nothing to block on
+                jax.block_until_ready(inflight)
             # Phase 1b: cross-host agreement (paper: xTaskNotify + semaphore).
             self.sync_fn()
             t1 = time.perf_counter()

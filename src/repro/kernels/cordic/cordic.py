@@ -19,6 +19,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+import jax.experimental.pallas.tpu as pltpu
 from repro.core.cordic import (
     HALF_PI_Q16,
     PI_Q16,
@@ -26,7 +27,7 @@ from repro.core.cordic import (
     atan_table,
     gain_inverse,
 )
-from repro.compat import CompilerParams, default_interpret
+from repro.kernels import default_interpret
 
 __all__ = ["cordic_kernel_call", "LANE", "DEFAULT_BLOCK_ROWS"]
 
@@ -97,7 +98,7 @@ def cordic_kernel_call(
             jax.ShapeDtypeStruct((rows, LANE), jnp.int32),
             jax.ShapeDtypeStruct((rows, LANE), jnp.int32),
         ],
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(flat)
     return (

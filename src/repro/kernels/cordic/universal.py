@@ -23,6 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+import jax.experimental.pallas.tpu as pltpu
 from repro.core.cordic import (
     HYPER_STAGES,
     atan2_q16_body,
@@ -33,7 +34,7 @@ from repro.core.cordic import (
     sqrt_q16_body,
     tanh_q16_body,
 )
-from repro.compat import CompilerParams, default_interpret
+from repro.kernels import default_interpret
 from repro.kernels.cordic.cordic import DEFAULT_BLOCK_ROWS, LANE
 
 __all__ = ["UNARY_OPS", "universal_kernel_call", "atan2_kernel_call", "div_kernel_call"]
@@ -81,7 +82,7 @@ def _blocked_call(kernel, inputs, *, block_rows: int, interpret: Optional[bool])
         in_specs=[spec] * len(flats),
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.int32),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*flats)
     return out.reshape(-1)[:n].reshape(shape)

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import CompilerParams, default_interpret
+from repro.kernels import default_interpret
 
 __all__ = ["flash_attention_call", "DEFAULT_BQ", "DEFAULT_BK"]
 
@@ -134,7 +134,7 @@ def flash_attention_call(
             pltpu.VMEM((bq_, 1), jnp.float32),
             pltpu.VMEM((bq_, Dv), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

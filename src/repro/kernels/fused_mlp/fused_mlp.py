@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import CompilerParams, default_interpret
+from repro.kernels import default_interpret
 from repro.core.cordic import sigmoid_q16_body
 
 __all__ = [
@@ -208,7 +208,7 @@ def fused_swiglu_kernel_call(
             pltpu.VMEM((bm_, bn_), jnp.int32),
             pltpu.VMEM((bm_, bn_), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

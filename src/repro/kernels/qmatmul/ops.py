@@ -10,7 +10,7 @@
                    used by the FAST training path: quantized forward,
                    float backward.
 
-``interpret=None`` auto-detects via ``repro.compat.default_interpret``:
+``interpret=None`` auto-detects via ``repro.kernels.default_interpret``:
 compiled Mosaic kernels on TPU, interpreter everywhere else.
 """
 

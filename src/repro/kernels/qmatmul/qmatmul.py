@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import CompilerParams, default_interpret
+from repro.kernels import default_interpret
 
 __all__ = ["qmatmul_kernel_call", "DEFAULT_BM", "DEFAULT_BN", "DEFAULT_BK"]
 
@@ -148,7 +148,7 @@ def qmatmul_kernel_call(
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,11 +1,16 @@
 """Serving driver: batched generation with runtime precision modes.
 
-Smoke (CPU):
+On the CPU, at smoke widths (``--smoke``; the tests run this way):
     PYTHONPATH=src python -m repro.launch.serve --arch gemma2_2b --smoke
-    PYTHONPATH=src python -m repro.launch.serve --arch gemma2_2b --continuous
-    PYTHONPATH=src python -m repro.launch.serve --arch deepseek_7b \
+    PYTHONPATH=src python -m repro.launch.serve --arch gemma2_2b --smoke --continuous
+    PYTHONPATH=src python -m repro.launch.serve --arch deepseek_7b --smoke \
         --continuous --paged --prefix-sharing \
         --metrics-out metrics.prom --trace-out trace.json
+
+On a TPU, at published widths with only the depth cut (``--layers``):
+    PYTHONPATH=src python -m repro.launch.serve --arch deepseek_7b --layers 4 \
+        --continuous --slots 8 --max-len 1536 --prompt-lens 256,1024 \
+        --levels f32,q16_16 --max-new 24
 
 ``--continuous`` runs the continuous-batching engine (per-request
 precision via ``--levels``) on a mixed-length/mixed-budget workload;
@@ -13,7 +18,12 @@ the default runs the static lock-step ``BatchedServer``.  Both routes
 build ONE :class:`~repro.runtime.config.ServingConfig` — assembled by
 :func:`serving_config_from_args`, which is what
 tests/test_serve_cli.py pins: every cache/telemetry flag must round-trip
-into a validated config.  ``--continuous --speculative`` serves every
+into a validated config.  The model comes from
+:func:`model_config_from_args`: the published config of ``--arch``,
+cut to ``--layers`` (never a width), or its smoke reduction under
+``--smoke``.  Without ``--prompt-lens`` the launcher serves four short
+built-in prompts; with it, one seeded random prompt per length.
+``--continuous --speculative`` serves every
 request through ladder-speculative decoding (draft at ``--draft-level``,
 verify at f32 — output identical to vanilla f32 greedy; watch
 ``spec_rounds`` / ``spec_accepted`` in the printed stats).  ``--paged``
@@ -26,21 +36,55 @@ Telemetry outputs (see docs/observability.md): ``--metrics-out FILE``
 writes the Prometheus text exposition after serving; ``--trace-out
 FILE`` writes the Chrome ``trace_event`` JSON (open in Perfetto).
 Either flag turns the profiler tier on for the run.
+
+The persistent compilation cache is turned on before the first compile
+(:func:`enable_compile_cache`).
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
+from pathlib import Path
 
 import jax
+import numpy as np
 
 MAX_LEN = 128
+
+#: The persistent compilation cache's home when ``JAX_COMPILATION_CACHE_DIR``
+#: is unset: a fixed directory in the checkout, so a later run finds what
+#: an earlier one cached (no temporary names).
+REPO_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+BUILTIN_PROMPTS = ([1, 2, 3, 4, 5], [10, 11, 12], [7, 7, 7, 7], [3, 1, 4, 1, 5, 9])
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; call before the first
+    compile.  ``JAX_COMPILATION_CACHE_DIR`` wins where it is set, and no
+    other directory is configured then; otherwise the cache lives at
+    :data:`REPO_CACHE_DIR`.  Returns the directory in use."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(REPO_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(prog="repro.launch.serve")
     ap.add_argument("--arch", required=True)
-    ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the arch's CPU smoke reduction instead of its "
+                         "published widths")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="depth cut: serve this many layers (a multiple of the "
+                         "layer period); every width stays as published")
+    ap.add_argument("--max-len", type=int, default=MAX_LEN,
+                    help="context length of every cache slot")
+    ap.add_argument("--prompt-lens", default=None,
+                    help="comma list of prompt lengths: one seeded random prompt "
+                         "per length instead of the four built-in prompts")
     ap.add_argument("--mode", default="precise", choices=["precise", "fast"])
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--continuous", action="store_true",
@@ -78,6 +122,38 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
+def model_config_from_args(args):
+    """The one flags -> :class:`ModelConfig` mapping: the published
+    config of ``--arch`` (or its smoke reduction), cut to ``--layers``."""
+    from repro.configs import get_config, smoke
+
+    cfg = smoke(args.arch) if args.smoke else get_config(args.arch)
+    if args.layers is not None:
+        period = len(cfg.period)
+        if args.layers < period or args.layers % period:
+            raise ValueError(
+                f"--layers {args.layers} must be a positive multiple of "
+                f"{cfg.name}'s {period}-layer period"
+            )
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
+    return cfg
+
+
+def random_prompts(lens, vocab: int, seed: int):
+    """One seeded random prompt per length, token ids drawn from
+    ``[1, vocab)``."""
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, vocab, size=n).tolist() for n in lens]
+
+
+def prompts_from_args(args, vocab: int, seed: int):
+    """The built-in prompts, or one seeded random prompt per
+    ``--prompt-lens`` entry."""
+    if not args.prompt_lens:
+        return [list(p) for p in BUILTIN_PROMPTS]
+    return random_prompts([int(n) for n in args.prompt_lens.split(",")], vocab, seed)
+
+
 def serving_config_from_args(args):
     """The one flags -> :class:`ServingConfig` mapping (validated by
     the config's own ``__post_init__``)."""
@@ -87,7 +163,7 @@ def serving_config_from_args(args):
 
     spec = (
         SpeculativeConfig(k=args.spec_k, draft_level=args.draft_level,
-                          max_len=MAX_LEN)
+                          max_len=args.max_len)
         if args.speculative else None
     )
     telemetry = TelemetryConfig(
@@ -95,7 +171,7 @@ def serving_config_from_args(args):
         trace=bool(args.trace_out),
     )
     return ServingConfig(
-        n_slots=args.slots, max_len=MAX_LEN, speculative=spec,
+        n_slots=args.slots, max_len=args.max_len, speculative=spec,
         cache="paged" if args.paged else "contiguous",
         page_size=args.page_size, prefill_chunk=args.prefill_chunk,
         prefix_sharing=args.prefix_sharing, n_pages=args.n_pages,
@@ -116,19 +192,23 @@ def _write_outputs(srv, args) -> None:
 def main(argv=None):
     args = build_parser().parse_args(argv)
 
-    from repro.configs import smoke
     from repro.core.precision import Mode
     from repro.models import init_params
     from repro.runtime.config import ServingConfig
     from repro.runtime.scheduler import Request
     from repro.runtime.serve import BatchedServer, ContinuousBatchingServer
 
-    cfg = smoke(args.arch)
+    dev = jax.devices()
+    print(f"device: platform={dev[0].platform} kind={dev[0].device_kind} "
+          f"count={len(dev)}")
+    print(f"compile cache: {enable_compile_cache()}")
+    cfg = model_config_from_args(args)
+    scfg = serving_config_from_args(args)
     params = init_params(cfg, jax.random.PRNGKey(0))
-    prompts = [[1, 2, 3, 4, 5], [10, 11, 12], [7, 7, 7, 7], [3, 1, 4, 1, 5, 9]]
+    prompts = prompts_from_args(args, cfg.vocab, scfg.seed)
 
     if args.continuous:
-        srv = ContinuousBatchingServer(cfg, params, serving_config_from_args(args))
+        srv = ContinuousBatchingServer(cfg, params, scfg)
         levels = args.levels.split(",") if args.levels else [None]
         reqs = [
             Request(rid=srv.next_rid(), prompt=p, max_new=args.max_new + 4 * (i % 2),
@@ -150,7 +230,7 @@ def main(argv=None):
 
     srv = BatchedServer(
         cfg, params,
-        ServingConfig(n_slots=4, max_len=MAX_LEN, max_new=args.max_new,
+        ServingConfig(n_slots=4, max_len=args.max_len, max_new=args.max_new,
                       default_level=Mode(args.mode),
                       telemetry=TelemetryConfig(
                           enabled=bool(args.metrics_out or args.trace_out),

@@ -18,6 +18,7 @@ out of scope per the assignment.
 from __future__ import annotations
 
 import functools
+import inspect
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -345,6 +346,32 @@ def _scan_with_caches(params, x, caches, cfg, positions, mode, constrain, *,
     return x, new_caches
 
 
+def _exact_at_highest(step):
+    """Run a serving step's matmuls at ``highest`` precision when it is
+    called with mode="exact", and at the backend default otherwise.
+
+    On a TPU an f32 matmul at default precision rounds both operands to
+    bf16 (one MXU pass).  That turns the f32-scale accumulation-order
+    noise between a prefill and a decode derivation of the same prefix
+    into bf16-ulp steps, and near-tied logits then rank differently.
+    The precision is set while the step is traced and only for the
+    exact rung, so the cheaper rungs keep the default.
+    """
+    sig = inspect.signature(step)
+
+    @functools.wraps(step)
+    def run(*args, **kwargs):
+        bound = sig.bind(*args, **kwargs)
+        bound.apply_defaults()
+        if bound.arguments["mode"] != "exact":
+            return step(*args, **kwargs)
+        with jax.default_matmul_precision("highest"):
+            return step(*args, **kwargs)
+
+    return run
+
+
+@_exact_at_highest
 def prefill_step(
     params,
     tokens,
@@ -377,6 +404,7 @@ def prefill_step(
     return softcap(logits, cfg.final_softcap, mode), new_caches
 
 
+@_exact_at_highest
 def decode_step(
     params,
     token,
@@ -418,6 +446,7 @@ def decode_step(
     return softcap(logits, cfg.final_softcap, mode), new_caches
 
 
+@_exact_at_highest
 def segment_step(
     params,
     tokens,

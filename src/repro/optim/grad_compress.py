@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
-
 __all__ = ["compressed_mean", "make_dp_train_step"]
 
 
@@ -120,7 +118,7 @@ def make_dp_train_step(cfg, opt_cfg, mesh, *, compress_bits: Optional[int] = 8, 
     rep = P()
     bspec = {"tokens": P("data"), "labels": P("data")}
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(rep, rep, rep, bspec),

@@ -429,7 +429,7 @@ def test_server_temperature_sampling_on_device():
 
 
 def test_default_interpret_off_tpu(rng):
-    from repro.compat import default_interpret
+    from repro.kernels import default_interpret
 
     assert default_interpret() is (jax.default_backend() != "tpu")
     # interpret=None flows through every kernel entrypoint

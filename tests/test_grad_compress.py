@@ -18,7 +18,6 @@ import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.optim.grad_compress import compressed_mean
 
 mesh = jax.make_mesh((8,), ("data",))
@@ -32,7 +31,7 @@ def worker(g_local, r_local):
     exact = {"w": jax.lax.pmean(g_local, "data")}
     return mean, new_res, exact
 
-f = jax.jit(shard_map(worker, mesh=mesh,
+f = jax.jit(jax.shard_map(worker, mesh=mesh,
     in_specs=(P("data"), P("data")), out_specs=(P("data"), P("data"), P("data")),
     check_vma=False))
 gl = jnp.asarray(g_global.reshape(8 * 64, 33))

@@ -88,6 +88,32 @@ def test_barrier_ordering():
     assert ev.total_s >= ev.swap_s >= 0
 
 
+def test_barrier_host_only_inflight_completes():
+    """Host values (ints, floats, None leaves, mixed pytrees) have
+    nothing to block on: the transition runs both phases."""
+    events = []
+    b = TwoPhaseBarrier(sync_fn=lambda: events.append("sync"))
+    inflight = {"step": 3, "loss": 1.5, "aux": None, "mix": [np.ones(2), jnp.ones(2)]}
+    b.transition(inflight=inflight, swap_fn=lambda: events.append("swap"))
+    assert events == ["sync", "swap"]
+    assert len(b.events) == 1
+
+
+def test_barrier_propagates_error_raised_while_blocking():
+    """A device error surfacing at the quiesce point must reach the
+    caller, and the swap must not happen."""
+
+    class FailingValue:
+        def block_until_ready(self):
+            raise RuntimeError("device lost")
+
+    swapped = []
+    b = TwoPhaseBarrier(sync_fn=lambda: None)
+    with pytest.raises(RuntimeError, match="device lost"):
+        b.transition(inflight=[FailingValue()], swap_fn=lambda: swapped.append(1))
+    assert swapped == [] and b.events == []
+
+
 def test_compile_op_aot_paths():
     """AOT-compiled executables dispatch correctly in both modes."""
     eng = MathEngine(Mode.PRECISE)

@@ -191,6 +191,22 @@ def test_serving_decode_consistency_all_families(arch):
     assert out == seq, (arch, out, seq)
 
 
+@pytest.mark.parametrize("mode,highest", [("exact", True), ("fast", False), ("precise", False)])
+def test_exact_rung_matmuls_traced_at_highest(mode, highest):
+    """Every matmul of an exact-mode serving step carries ``highest``
+    precision (a TPU otherwise runs an f32 matmul as one bf16 pass); no
+    matmul of the other rungs does."""
+    cfg = smoke("deepseek_7b")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    tok, pos = jnp.zeros((2, 1), jnp.int32), jnp.zeros((2,), jnp.int32)
+    caches = init_caches(cfg, 2, 16, dtype=jnp.float32)
+    text = jax.jit(lambda p, t, q, c: decode_step(p, t, q, c, cfg, mode=mode)).lower(
+        params, tok, pos, caches).as_text()
+    dots = [line for line in text.splitlines() if "dot_general" in line]
+    assert dots
+    assert [("HIGHEST" in line) for line in dots] == [highest] * len(dots)
+
+
 def test_server_mode_switch_o1():
     cfg = smoke("deepseek_7b")
     params = init_params(cfg, jax.random.PRNGKey(5))
