@@ -1,0 +1,10 @@
+"""The chip benchmark's CPU tests: the checkout root (for ``chipbench``)
+and ``src`` (for the program) go on the import path."""
+
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (ROOT / "src", ROOT):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
