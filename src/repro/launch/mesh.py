@@ -12,10 +12,19 @@ import jax
 __all__ = ["make_production_mesh", "make_mesh_by_name", "HW"]
 
 
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes: JAX 0.9 defaults to
+    ``Explicit`` axes, which ``with_sharding_constraint`` in
+    ``launch/steps.py`` refuses."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh_by_name(name: str):
@@ -25,7 +34,7 @@ def make_mesh_by_name(name: str):
         return make_production_mesh(multi_pod=True)
     if name == "host":  # whatever this process actually has (tests)
         n = len(jax.devices())
-        return jax.make_mesh((1, n), ("data", "model"))
+        return _auto_mesh((1, n), ("data", "model"))
     raise ValueError(name)
 
 

@@ -435,17 +435,21 @@ class PagedCachePool:
 
     def device_tables(self):
         """Device mirror of the block tables (a jit ARGUMENT — content
-        changes never retrace)."""
+        changes never retrace).  A copy, never a view: the host edits
+        its tables in place while dispatched steps may not yet have
+        read the mirror, and on the CPU ``jnp.asarray`` of an aligned
+        array shares its memory."""
         if self._dirty or self._tables_dev is None:
             self._tables_dev = {
-                gk: jnp.asarray(g["table"]) for gk, g in self.groups.items()
+                gk: jnp.array(g["table"]) for gk, g in self.groups.items()
             }
             self._dirty = False
         return self._tables_dev
 
     def slot_tables(self, slot: int):
-        """One slot's table rows (device), for the B=1 admission path."""
-        return {gk: jnp.asarray(g["table"][slot]) for gk, g in self.groups.items()}
+        """One slot's table rows (device, a copy as in
+        :meth:`device_tables`), for the B=1 admission path."""
+        return {gk: jnp.array(g["table"][slot]) for gk, g in self.groups.items()}
 
     def scatter_ids(self, slot: int):
         """Per-group scatter targets for a whole-slot commit: the

@@ -1,8 +1,11 @@
 """Test harness config.
 
-IMPORTANT: no XLA_FLAGS / device-count overrides here — smoke tests and
-benches must see the real single CPU device.  Multi-device sharding
-tests spawn subprocesses with their own XLA_FLAGS (see
+The one XLA flag every test process gets is set in the checkout's root
+``conftest.py``: it turns off an LLVM pass of XLA's CPU backend that
+costs minutes on CORDIC kernels and changes no result.  The device
+count is never overridden here — smoke tests and benches must see the
+real single CPU device.  Multi-device sharding tests spawn subprocesses
+that append their own device count to ``XLA_FLAGS`` (see
 tests/test_dryrun.py).
 
 Property-based tests go through ``tests/_pbt.py``, which re-exports

@@ -48,11 +48,6 @@ from repro.runtime.speculative import (
     SpeculativeConfig,
 )
 
-#: families the spec suite sweeps: sliding-window local/global
-#: attention (gemma2), hybrid attention+SSM+MoE (jamba), and latent
-#: attention (minicpm3 MLA) — every cache kind the rollback must handle.
-FAMILIES = ("gemma2_2b", "jamba_v01_52b", "minicpm3_4b")
-
 DRAFT_RUNGS = ("q8_8", "q16_16")
 
 #: fixed prompt-length pool: seeds vary CONTENT, not shapes, so the

@@ -282,6 +282,25 @@ def test_paged_write_read_roundtrip_and_free():
                        init_caches(cfg, 1, 32, dtype=jnp.float32))
 
 
+def test_device_tables_are_snapshots_of_the_host_tables():
+    """A device table handed to a dispatched step keeps the content it
+    had when it was taken.  The host edits its tables in place at the
+    next admission or eviction, often before an asynchronously
+    dispatched step has read them; on the CPU ``jnp.asarray`` of an
+    aligned NumPy array shares its memory, so an alias would let those
+    edits reach the step.  Whether an array is aligned depends on where
+    it was allocated, hence tables of many sizes."""
+    cfg = smoke("deepseek-7b")
+    for n_slots in range(1, 25):
+        pool = PagedCachePool(cfg, n_slots=n_slots, max_len=32, page_size=8)
+        state = pool.alloc()
+        tables, row = pool.device_tables(), pool.slot_tables(0)
+        state = pool.ensure_rows(state, 0, 0, 31)   # attaches every block
+        assert pool.groups["L32"]["table"][0].all()
+        assert not np.asarray(tables["L32"]).any(), n_slots
+        assert not np.asarray(row["L32"]).any(), n_slots
+
+
 def test_paged_commit_rows_masked_lane_untouched():
     cfg, pool, state = _mk_pool()
     state = pool.ensure_rows(state, 0, 0, 0)

@@ -63,7 +63,9 @@ def test_trip_count_multiplication():
 
 DRYRUN_SNIPPET = """
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (
+    os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
+)
 import sys, json
 import jax
 from jax.sharding import Mesh
@@ -71,7 +73,8 @@ from repro.launch.steps import build_cell
 from repro.launch import dryrun
 import numpy as np
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 dryrun.make_mesh_by_name = lambda name: mesh  # shrink to the host's 8 devices
 rec = dryrun.run_cell("{arch}", "{shape}", "host8", verbose=False)
 print("RESULT:" + json.dumps({{"status": rec["status"],
@@ -91,7 +94,7 @@ def test_mini_dryrun_subprocess(arch, shape):
     env["PYTHONPATH"] = "src"
     out = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True,
-        cwd=Path(__file__).parent.parent, timeout=560, env=env,
+        cwd=Path(__file__).parent.parent, timeout=120, env=env,
     )
     assert out.returncode == 0, out.stderr[-2000:]
     line = [l for l in out.stdout.splitlines() if l.startswith("RESULT:")][0]

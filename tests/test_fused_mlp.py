@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from _reference import stepwise
 from repro.core import quantization
 from repro.core.quantization import QuantizedWeightCache, quantize_pow2
 from repro.kernels.fused_mlp.fused_mlp import fused_swiglu_kernel_call
@@ -353,11 +354,19 @@ def test_server_weight_cache_populated_once():
 
 
 def test_server_greedy_matches_teacher_forcing_fast_level():
-    """Greedy decode at the FAST level (fused path) must equal argmax of
-    the FAST prefill at each position — prefill and decode share the
-    fused kernel-equivalent path, so consistency is preserved."""
+    """Greedy decode at the FAST level (fused path) must equal the FAST
+    rung's own step-by-step re-derivation: a FAST prefill of the prompt,
+    then one FAST decode step per token, with the weights quantized in
+    the graph (the server quantizes them once at build).
+
+    The reference is not a prefill of the growing sequence: FAST
+    activations share one power-of-two exponent per tensor
+    (``_quant_dims``), so a whole-sequence prefill puts the last
+    position on the grid of the sequence's largest activation, while a
+    one-token decode uses that token's own.  The two derivations differ
+    by up to one grid step, enough to flip a near-tied argmax."""
     from repro.configs.gemma2_2b import CONFIG
-    from repro.models import init_caches, init_params, prefill_step
+    from repro.models import init_params
     from repro.models.config import smoke_config
     from repro.runtime.serve import BatchedServer, ServerConfig
 
@@ -368,14 +377,7 @@ def test_server_greedy_matches_teacher_forcing_fast_level():
         cfg, params, ServerConfig(max_batch=1, max_len=64, max_new=4, start_mode="q16_16")
     )
     out = srv.generate([prompt])[0]
-
-    seq = list(prompt)
-    for _ in range(4):
-        caches = init_caches(cfg, 1, 64)
-        logits, _ = jax.jit(lambda p, t, c: prefill_step(p, t, c, cfg, mode="fast"))(
-            srv.params, jnp.asarray([seq], jnp.int32), caches
-        )
-        seq.append(int(jnp.argmax(logits[0])))
+    seq = stepwise(cfg, params, prompt, 4, "fast")
     assert out == seq, (out, seq)
 
 

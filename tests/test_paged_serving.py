@@ -46,29 +46,54 @@ PROMPTS = [
 ]
 
 
-_MODELS = {}
-_ALONE = {}
-
-
-def _model(arch):
-    if arch not in _MODELS:
-        cfg = smoke(arch)
-        _MODELS[arch] = (cfg, init_params(cfg, jax.random.PRNGKey(0)))
-    return _MODELS[arch]
-
-
 def _paged(n_slots=2, **kw):
     kw.setdefault("max_len", MAX_LEN)
     return ServingConfig(n_slots=n_slots, cache="paged", page_size=4, **kw)
 
 
-def _serve_alone(arch, prompt, max_new, level):
-    """Reference output: the prompt served by itself through a 1-slot
-    paged server (memoized per arch — jit compiles dominate runtime)."""
-    if arch not in _ALONE:
-        cfg, params = _model(arch)
-        _ALONE[arch] = ContinuousBatchingServer(cfg, params, _paged(n_slots=1))
-    return _ALONE[arch].generate([prompt], max_new=max_new, level=level)[0]
+@pytest.fixture(scope="module")
+def model():
+    """``model(arch) -> (cfg, params)``, built once per file."""
+    built = {}
+
+    def get(arch):
+        if arch not in built:
+            cfg = smoke(arch)
+            built[arch] = (cfg, init_params(cfg, jax.random.PRNGKey(0)))
+        return built[arch]
+
+    return get
+
+
+@pytest.fixture(scope="module")
+def server(model):
+    """``server(arch, n_slots=2, cache="paged")``: one compiled server per
+    key, shared by the file's tests (jit compiles dominate runtime).
+    Every request a test sends finishes, so each test finds the pool
+    drained; tests assert on their own outputs and on counter
+    differences, never on a shared server's totals."""
+    built = {}
+
+    def get(arch, n_slots=2, cache="paged"):
+        key = (arch, n_slots, cache)
+        if key not in built:
+            cfg, params = model(arch)
+            scfg = (_paged(n_slots=n_slots) if cache == "paged"
+                    else ServingConfig(n_slots=n_slots, max_len=MAX_LEN))
+            built[key] = ContinuousBatchingServer(cfg, params, scfg)
+        return built[key]
+
+    return get
+
+
+@pytest.fixture(scope="module")
+def serve_alone(server):
+    """Reference output: the prompt served by itself, the only request
+    in the shared 2-slot paged server."""
+    def run(arch, prompt, max_new, level):
+        return server(arch).generate([prompt], max_new=max_new, level=level)[0]
+
+    return run
 
 
 # ---------------------------------------------------------------------------
@@ -77,25 +102,23 @@ def _serve_alone(arch, prompt, max_new, level):
 
 
 @pytest.mark.parametrize("arch", ["deepseek-7b", "gemma2-2b", "jamba-v0.1-52b"])
-def test_paged_batch_equals_alone(arch):
+def test_paged_batch_equals_alone(arch, server, serve_alone):
     """Mixed-length batch through the paged pool == each request served
     alone, across attention families (full GQA, SWA, hybrid SSM)."""
-    cfg, params = _model(arch)
-    srv = ContinuousBatchingServer(cfg, params, _paged(n_slots=2))
+    srv = server(arch)
     outs = srv.generate(PROMPTS, max_new=6, level="f32")
     for p, o in zip(PROMPTS, outs):
-        assert o == _serve_alone(arch, p, 6, "f32")
+        assert o == serve_alone(arch, p, 6, "f32")
     # every request finished -> every page returned to the free list
     for g in srv.cache_ops.groups.values():
         assert g["alloc"].live() == []
 
 
-def test_paged_mixed_levels_equal_alone():
+def test_paged_mixed_levels_equal_alone(server, serve_alone):
     """Per-request precision through the paged pool: each lane's output
     equals serving it alone AT ITS LEVEL (isolation holds through the
     gather/scatter path and the pristine-masked mixed-level pass)."""
-    cfg, params = _model("deepseek-7b")
-    srv = ContinuousBatchingServer(cfg, params, _paged(n_slots=4))
+    srv = server("deepseek-7b", n_slots=4)
     levels = ["q16_16", "f32", "q16_16", "f32"]
     reqs = [
         Request(rid=srv.next_rid(), prompt=p, max_new=5, level=lv)
@@ -103,50 +126,46 @@ def test_paged_mixed_levels_equal_alone():
     ]
     fins = srv.serve(reqs)
     for r, lv in zip(reqs, levels):
-        assert fins[r.rid].tokens == _serve_alone("deepseek-7b", r.prompt, 5, lv)
+        assert fins[r.rid].tokens == serve_alone("deepseek-7b", r.prompt, 5, lv)
 
 
-def test_paged_speculative_equals_vanilla_f32():
+def test_paged_speculative_equals_vanilla_f32(model, server):
     """Ladder-speculative serving through the paged pool is
     token-identical to paged vanilla f32 — k+1-row scatter including
     the rolled-back rejected rows is a bit-exact page restore."""
-    cfg, params = _model("deepseek-7b")
+    cfg, params = model("deepseek-7b")
     spec = SpeculativeConfig(k=3, max_len=MAX_LEN)
     s_spec = ContinuousBatchingServer(
         cfg, params, _paged(n_slots=2, speculative=spec)
     )
     o_spec = s_spec.generate(PROMPTS, max_new=6, speculative=True)
-    s_van = ContinuousBatchingServer(cfg, params, _paged(n_slots=2))
-    o_van = s_van.generate(PROMPTS, max_new=6, level="f32")
+    o_van = server("deepseek-7b").generate(PROMPTS, max_new=6, level="f32")
     assert o_spec == o_van
     assert s_spec.stats["spec_rounds"] > 0
     for g in s_spec.cache_ops.groups.values():
         assert g["alloc"].live() == []
 
 
-def test_paged_slot_churn_and_reuse():
+def test_paged_slot_churn_and_reuse(server, serve_alone):
     """Many more requests than slots: slots recycle through
     free_slot/re-admission and late requests still match serving
     alone (no residue from prior occupants' pages)."""
-    cfg, params = _model("gemma2-2b")
     prompts = [[(7 * i + j) % 120 + 1 for j in range(3 + (5 * i) % 9)]
                for i in range(7)]
-    srv = ContinuousBatchingServer(cfg, params, _paged(n_slots=2))
+    srv = server("gemma2-2b")
     outs = srv.generate(prompts, max_new=4, level="f32")
     for p, o in zip(prompts, outs):
-        assert o == _serve_alone("gemma2-2b", p, 4, "f32")
+        assert o == serve_alone("gemma2-2b", p, 4, "f32")
     for g in srv.cache_ops.groups.values():
         assert g["alloc"].live() == []
 
 
-def test_paged_eos_mode():
+def test_paged_eos_mode(model, server):
     """EOS-checked serving (per-step host pull) through the paged pool:
     finishes match the contiguous engine's."""
-    cfg, params = _model("deepseek-7b")
-    base = ContinuousBatchingServer(
-        cfg, params, ServingConfig(n_slots=2, max_len=MAX_LEN)
-    )
-    o_base = base.generate(PROMPTS, max_new=8, level="f32")
+    cfg, params = model("deepseek-7b")
+    o_base = server("deepseek-7b", cache="contiguous").generate(
+        PROMPTS, max_new=8, level="f32")
     eos = int(o_base[0][len(PROMPTS[0]) + 1])  # force an early EOS for req 0
     s_c = ContinuousBatchingServer(
         cfg, params, ServingConfig(n_slots=2, max_len=MAX_LEN, eos_id=eos)
@@ -163,12 +182,12 @@ def test_paged_eos_mode():
 # ---------------------------------------------------------------------------
 
 
-def test_chunked_prefill_zero_retraces_across_lengths():
+def test_chunked_prefill_zero_retraces_across_lengths(server):
     """The counting hook: the chunk step traces once per ladder level
     during warmup and NEVER again, whatever prompt lengths arrive —
     the per-length retrace cost of the contiguous prefill is gone."""
-    cfg, params = _model("deepseek-7b")
-    srv = ContinuousBatchingServer(cfg, params, _paged(n_slots=2))
+    srv = server("deepseek-7b")
+    chunks0 = srv.stats["prefill_chunks"]
     srv.generate([[1, 2, 3]], max_new=2, level="f32")  # warmup
     traced = srv._chunk_traces
     assert traced == len(srv.level_names)  # one switch trace covers all rungs
@@ -179,20 +198,20 @@ def test_chunked_prefill_zero_retraces_across_lengths():
     # and the chunk ledger matches ceil(len/C) per admission
     C = srv.scfg.resolved_chunk
     expect = -(-3 // C) + 2 * sum(-(-len(p) // C) for p in burst)
-    assert srv.stats["prefill_chunks"] == expect
+    assert srv.stats["prefill_chunks"] - chunks0 == expect
 
 
-def test_chunk_size_config():
+def test_chunk_size_config(model, serve_alone):
     """prefill_chunk is honored (and validated: must divide max_len;
     prefix sharing pins chunk == page_size)."""
-    cfg, params = _model("deepseek-7b")
+    cfg, params = model("deepseek-7b")
     srv = ContinuousBatchingServer(
         cfg, params,
         ServingConfig(n_slots=1, max_len=MAX_LEN, cache="paged",
                       page_size=4, prefill_chunk=8),
     )
     out = srv.generate([PROMPTS[1]], max_new=4, level="f32")[0]
-    assert out == _serve_alone("deepseek-7b", PROMPTS[1], 4, "f32")
+    assert out == serve_alone("deepseek-7b", PROMPTS[1], 4, "f32")
     assert srv.stats["prefill_chunks"] == -(-len(PROMPTS[1]) // 8)
     with pytest.raises(ValueError, match="divide max_len"):
         ServingConfig(cache="paged", max_len=32, page_size=4, prefill_chunk=5)
@@ -206,14 +225,16 @@ def test_chunk_size_config():
 # ---------------------------------------------------------------------------
 
 
-def test_prefix_sharing_token_identical_and_counted():
+def test_prefix_sharing_token_identical_and_counted(model, server):
     """Sharing ON == sharing OFF token-for-token, with hits recorded
     and fewer chunk dispatches (the reused prefix is never re-run)."""
-    cfg, params = _model("deepseek-7b")
+    cfg, params = model("deepseek-7b")
     shared = list(range(1, 13))  # 3 full pages of 4
     prompts = [shared + [50 + i, 70 + i] for i in range(4)]
-    s_off = ContinuousBatchingServer(cfg, params, _paged(n_slots=2))
+    s_off = server("deepseek-7b")
+    chunks_off = s_off.stats["prefill_chunks"]
     o_off = s_off.generate(prompts, max_new=5, level="f32")
+    chunks_off = s_off.stats["prefill_chunks"] - chunks_off
     s_on = ContinuousBatchingServer(
         cfg, params, _paged(n_slots=2, prefix_sharing=True)
     )
@@ -221,7 +242,7 @@ def test_prefix_sharing_token_identical_and_counted():
     assert o_on == o_off
     assert s_on.stats["prefix_hits"] == 3         # every admission after the first
     assert s_on.stats["prefix_tokens_reused"] == 3 * 12
-    assert s_on.stats["prefill_chunks"] < s_off.stats["prefill_chunks"]
+    assert s_on.stats["prefill_chunks"] < chunks_off
     # slots drained; only prefix-cache entries keep pages resident
     g = s_on.cache_ops.groups[f"L{MAX_LEN}"]
     assert (g["table"] == 0).all()
@@ -230,9 +251,9 @@ def test_prefix_sharing_token_identical_and_counted():
     assert g["alloc"].live() == []
 
 
-def test_prefix_sharing_speculative_still_exact():
+def test_prefix_sharing_speculative_still_exact(model, server):
     """Sharing + speculative composed: still equals vanilla f32."""
-    cfg, params = _model("deepseek-7b")
+    cfg, params = model("deepseek-7b")
     shared = list(range(1, 9))
     prompts = [shared + [40 + i] for i in range(3)]
     spec = SpeculativeConfig(k=2, max_len=MAX_LEN)
@@ -241,13 +262,12 @@ def test_prefix_sharing_speculative_still_exact():
         _paged(n_slots=2, prefix_sharing=True, speculative=spec),
     )
     o = s.generate(prompts, max_new=5, speculative=True)
-    v = ContinuousBatchingServer(cfg, params, _paged(n_slots=2))
-    assert o == v.generate(prompts, max_new=5, level="f32")
+    assert o == server("deepseek-7b").generate(prompts, max_new=5, level="f32")
     assert s.stats["prefix_hits"] > 0
 
 
-def test_prefix_sharing_rejected_for_unshareable_models():
-    cfg, params = _model("gemma2-2b")
+def test_prefix_sharing_rejected_for_unshareable_models(model):
+    cfg, params = model("gemma2-2b")
     with pytest.raises(ValueError, match="prefix_sharing"):
         ContinuousBatchingServer(
             cfg, params, _paged(n_slots=2, prefix_sharing=True)
@@ -259,7 +279,7 @@ def test_prefix_sharing_rejected_for_unshareable_models():
 # ---------------------------------------------------------------------------
 
 
-def test_tight_pool_queues_admission_but_serves_all():
+def test_tight_pool_queues_admission_but_serves_all(model, serve_alone):
     """A page pool far smaller than slots x max_len: ``can_admit``
     holds requests in the queue instead of over-committing pages;
     every request still finishes and matches serving alone.
@@ -267,7 +287,7 @@ def test_tight_pool_queues_admission_but_serves_all():
     Sizing: 8 usable pages; each 10-token prompt needs 3 blocks at
     admission and grows to 4 by its last decode write, so at most two
     of the four slots can be resident at once."""
-    cfg, params = _model("deepseek-7b")
+    cfg, params = model("deepseek-7b")
     scfg = ServingConfig(
         n_slots=4, max_len=MAX_LEN, cache="paged", page_size=4, n_pages=9,
     )
@@ -275,7 +295,7 @@ def test_tight_pool_queues_admission_but_serves_all():
     prompts = [[(11 * i + j) % 120 + 1 for j in range(10)] for i in range(6)]
     outs = srv.generate(prompts, max_new=4, level="f32")
     for p, o in zip(prompts, outs):
-        assert o == _serve_alone("deepseek-7b", p, 4, "f32")
+        assert o == serve_alone("deepseek-7b", p, 4, "f32")
     for g in srv.cache_ops.groups.values():
         assert g["alloc"].live() == []
     assert srv.cache_ops.groups[f"L{MAX_LEN}"]["alloc"].high_water <= 8
@@ -301,17 +321,15 @@ def test_serving_config_validation():
     assert ServingConfig().resolved_chunk is None
 
 
-def test_deprecated_shims_warn_and_work():
-    cfg, params = _model("deepseek-7b")
+def test_deprecated_shims_warn_and_work(model, server):
+    cfg, params = model("deepseek-7b")
     with warnings.catch_warnings(record=True) as w:
         warnings.simplefilter("always")
         old = ContinuousServerConfig(n_slots=2, max_len=MAX_LEN)
         assert any(issubclass(x.category, DeprecationWarning) for x in w)
     assert isinstance(old, ServingConfig)  # pure alias
     srv_old = ContinuousBatchingServer(cfg, params, old)
-    srv_new = ContinuousBatchingServer(
-        cfg, params, ServingConfig(n_slots=2, max_len=MAX_LEN)
-    )
+    srv_new = server("deepseek-7b", cache="contiguous")
     assert srv_old.generate(PROMPTS[:2], max_new=4) == \
         srv_new.generate(PROMPTS[:2], max_new=4)
 
@@ -328,7 +346,7 @@ def test_deprecated_shims_warn_and_work():
     assert srv_b.generate(same_len) == srv_b2.generate(same_len)
 
 
-def test_batched_server_rejects_paged():
-    cfg, params = _model("deepseek-7b")
+def test_batched_server_rejects_paged(model):
+    cfg, params = model("deepseek-7b")
     with pytest.raises(ValueError, match="contiguous"):
         BatchedServer(cfg, params, _paged())

@@ -46,18 +46,16 @@ def test_quantized_decode_close_to_bf16(arch):
     # trajectories diverge chaotically — that would test chaos, not
     # quantization)
     forced = jnp.asarray(rng.integers(0, cfg.vocab, (4, B, 1)))
+    prefill = jax.jit(lambda p, t, c: prefill_step(p, t, c, cfg))
+    decode = jax.jit(lambda p, t, q, c: decode_step(p, t, q, c, cfg))
     outs = {}
     for quantized in (False, True):
         caches = init_caches(cfg, B, 64, quantized=quantized)
-        logits, caches = jax.jit(lambda p, t, c: prefill_step(p, t, c, cfg))(
-            params, toks, caches
-        )
+        logits, caches = prefill(params, toks, caches)
         pos = jnp.full((B,), S, jnp.int32)
         seq_logits = [np.asarray(logits, np.float32)]
         for i in range(4):
-            logits, caches = jax.jit(lambda p, t, q, c: decode_step(p, t, q, c, cfg))(
-                params, forced[i], pos, caches
-            )
+            logits, caches = decode(params, forced[i], pos, caches)
             seq_logits.append(np.asarray(logits, np.float32))
             pos = pos + 1
         outs[quantized] = np.stack(seq_logits)

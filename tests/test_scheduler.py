@@ -10,10 +10,11 @@ import jax.numpy as jnp
 import pytest
 
 from _pbt import given, settings, strategies as st
+from _reference import teacher_forced
 from repro.configs import smoke
 from repro.core.arbiter import SlotArbiter, SlotArbiterConfig
 from repro.runtime.scheduler import ContinuousScheduler, Request
-from repro.models import init_caches, init_params, prefill_step
+from repro.models import init_params
 from repro.runtime.serve import (
     ContinuousBatchingServer,
     ContinuousServerConfig,
@@ -326,37 +327,35 @@ def small_model():
     return cfg, params
 
 
-def _teacher_forced(cfg, params, prompt, n, level="f32"):
-    """Greedy reference: re-run prefill on the growing sequence at the
-    mode the serving level maps to."""
-    mode = dict(SERVE_STEP_LEVELS)[level]
-    seq = list(prompt)
-    for _ in range(n):
-        caches = init_caches(cfg, 1, 64, dtype=jnp.float32)
-        logits, _ = jax.jit(lambda p, t, c: prefill_step(p, t, c, cfg, mode=mode))(
-            params, jnp.asarray([seq], jnp.int32), caches
-        )
-        seq.append(int(jnp.argmax(logits[0])))
-    return seq
+@pytest.fixture(scope="module")
+def small_server(small_model):
+    """One compiled 2-slot server on ``small_model``, shared by the tests
+    that only need some server of that shape.  Every request they send
+    finishes, and they assert on their own requests and on counter
+    differences."""
+    cfg, params = small_model
+    return ContinuousBatchingServer(
+        cfg, params, ContinuousServerConfig(n_slots=2, max_len=64)
+    )
 
 
-def test_continuous_matches_teacher_forcing_under_churn(small_model):
+def test_continuous_matches_teacher_forcing_under_churn(small_model, small_server):
     """More requests than slots, mixed lengths and budgets: every
     request's greedy output must equal its teacher-forced reference —
     admission order, slot reuse and lock-step-free eviction must be
     invisible to each request."""
     cfg, params = small_model
-    srv = ContinuousBatchingServer(
-        cfg, params, ContinuousServerConfig(n_slots=2, max_len=64)
-    )
+    srv = small_server
+    prefills = srv.stats["prefills"]
     prompts = [[1, 2, 3, 4, 5, 6, 7, 8], [4, 5, 6], [9, 8, 7, 6, 5], [2, 2, 2, 2, 2, 2]]
     budgets = [3, 6, 2, 5]
     reqs = [Request(rid=srv.next_rid(), prompt=p, max_new=n)
             for p, n in zip(prompts, budgets)]
     fins = srv.serve(reqs)
-    assert srv.stats["prefills"] == 4
+    assert srv.stats["prefills"] - prefills == 4
     for r, p, n in zip(reqs, prompts, budgets):
-        assert fins[r.rid].tokens == _teacher_forced(cfg, params, p, n), r.rid
+        assert fins[r.rid].tokens == teacher_forced(
+            cfg, params, p, n, dict(SERVE_STEP_LEVELS)["f32"]), r.rid
         assert fins[r.rid].reason == "max_new"
 
 
@@ -385,20 +384,21 @@ def test_mixed_levels_identical_to_alone(arch):
     dispatch; includes the hybrid SSM+attention family)."""
     cfg = smoke(arch)
     params = init_params(cfg, jax.random.PRNGKey(4))
-    scfg = lambda: ContinuousServerConfig(n_slots=2, max_len=64)
     pa, pb = [1, 2, 3, 4, 5, 6], [9, 8, 7, 6]
 
-    srv = ContinuousBatchingServer(cfg, params, scfg())
+    srv = ContinuousBatchingServer(
+        cfg, params, ContinuousServerConfig(n_slots=2, max_len=64)
+    )
     fins = srv.serve([
         Request(rid=0, prompt=pa, max_new=4, level="f32"),
         Request(rid=1, prompt=pb, max_new=4, level="q16_16"),
     ])
     assert srv.stats["level_passes"] == 2 * srv.stats["decode_steps"]  # mixed batch
 
-    alone_a = ContinuousBatchingServer(cfg, params, scfg()).serve(
-        [Request(rid=0, prompt=pa, max_new=4, level="f32")])[0]
-    alone_b = ContinuousBatchingServer(cfg, params, scfg()).serve(
-        [Request(rid=1, prompt=pb, max_new=4, level="q16_16")])[1]
+    # served alone: one request at a time through the same compiled
+    # server, the other slot empty
+    alone_a = srv.serve([Request(rid=0, prompt=pa, max_new=4, level="f32")])[0]
+    alone_b = srv.serve([Request(rid=1, prompt=pb, max_new=4, level="q16_16")])[1]
     assert fins[0].tokens == alone_a.tokens
     assert fins[1].tokens == alone_b.tokens
     assert alone_a.tokens != alone_b.tokens  # distinct requests, sanity
@@ -448,14 +448,11 @@ def test_masked_lane_cache_magnitude_cannot_perturb_members(small_model):
     np.testing.assert_array_equal(l_clean, l_dirty)
 
 
-def test_unknown_level_rejected_before_slot_binding(small_model):
+def test_unknown_level_rejected_before_slot_binding(small_server):
     """Regression (review finding): an invalid Request.level must fail
     at submission — before a slot is bound — and leave the server fully
     usable (no zombie slot entries, no stranded predecessors)."""
-    cfg, params = small_model
-    srv = ContinuousBatchingServer(
-        cfg, params, ContinuousServerConfig(n_slots=2, max_len=64)
-    )
+    srv = small_server
     good = Request(rid=0, prompt=[1, 2, 3], max_new=2)
     bad = Request(rid=1, prompt=[4, 5], max_new=2, level="q8_8")  # not a serve level
     with pytest.raises(ValueError, match="unknown level"):
@@ -467,13 +464,10 @@ def test_unknown_level_rejected_before_slot_binding(small_model):
     assert len(outs[0]) == 5
 
 
-def test_server_lifetime_state_is_bounded(small_model):
+def test_server_lifetime_state_is_bounded(small_server):
     """serve() hands results out and drops them from the scheduler — a
     long-lived server must not accumulate per-request state forever."""
-    cfg, params = small_model
-    srv = ContinuousBatchingServer(
-        cfg, params, ContinuousServerConfig(n_slots=2, max_len=64)
-    )
+    srv = small_server
     for _ in range(3):
         srv.generate([[1, 2, 3], [4, 5]], max_new=2)
     assert srv.scheduler.finished == {}

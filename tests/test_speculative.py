@@ -1,112 +1,34 @@
-"""Ladder-speculative decoding exactness suite (driven by the
-reusable harness in tests/spec_harness.py).
+"""Ladder-speculative decoding: the acceptance simulator, config
+validation, and the continuous-batching server integration (spec slots
+exact under churn and in mixed traffic).
 
 The contract under test: drafting at a cheap rung and verifying at f32
 changes HOW FAST tokens appear, never WHICH tokens — the speculative
 stream is token-for-token identical to vanilla f32 greedy decode, the
 caches after a round are bit-identical to sequentially decoding only
 the accepted tokens, and the acceptance accounting matches a NumPy
-reference simulator.  Swept over every cache architecture (SWA, hybrid
-SSM, MLA) x draft rungs x seeds, plus the continuous-batching server
-integration (spec slots exact under churn and in mixed traffic).
+reference simulator.  The exactness sweep over every cache
+architecture (SWA, hybrid SSM, MLA) x draft rungs x seeds runs one
+family per file: ``tests/test_speculative_<family>.py`` over
+``tests/spec_sweep.py``.
 """
-
-import functools
 
 import jax
 import numpy as np
 import pytest
 
-from repro.models import init_params, smoke_config
+from repro.models import init_params
 from repro.runtime.scheduler import Request
 from repro.runtime.serve import ContinuousBatchingServer, ContinuousServerConfig
 from repro.runtime.speculative import (
     SPEC_DRAFT_LEVELS,
-    LadderSpeculativeDecoder,
     SpeculativeConfig,
     register_spec_steps,
 )
 from repro.core.precision import MathEngine
 
-from spec_harness import (
-    DRAFT_RUNGS,
-    FAMILIES,
-    ExactnessHarness,
-    family_config,
-    make_prompts,
-    simulate_acceptance,
-)
-
-SEEDS = (0, 1, 2, 3)
-
-
-@functools.lru_cache(maxsize=None)
-def harness(family: str, k: int = 3) -> ExactnessHarness:
-    """One compiled harness per (family, k), shared across the sweep."""
-    return ExactnessHarness(family, k=k)
-
-
-# ---------------------------------------------------------------------------
-# property 1: token exactness (3 families x 2 rungs x 4 seeds)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("family", FAMILIES)
-@pytest.mark.parametrize("rung", DRAFT_RUNGS)
-@pytest.mark.parametrize("seed", SEEDS)
-def test_token_exactness(family, rung, seed):
-    rep = harness(family).run_exactness(rung, seed)
-    assert rep.tokens_ok, (
-        f"{family}/{rung}/seed{seed}: speculative != vanilla f32 greedy\n"
-        f"  spec    {rep.speculative}\n  vanilla {rep.vanilla}"
-    )
-    # accounting: decoder counters == NumPy simulator replay of the trace
-    assert rep.accounting_ok, (rep.accounting, rep.simulator)
-    assert rep.accounting["rounds"] == rep.simulator["rounds"]
-    # every committed token is f32-verified, so each round commits >= 1
-    # per active lane: rounds never exceed total tokens emitted
-    assert 0.0 <= rep.acceptance_rate <= 1.0
-
-
-def test_acceptance_rates_vary_across_rungs_and_families():
-    """Sanity that the sweep exercises real speculation dynamics: the
-    measured acceptance rates are neither all-0 (drafts useless —
-    machinery untested beyond the trivial path) nor all-1 (rollback
-    never exercised)."""
-    rates = []
-    for family in FAMILIES:
-        for rung in DRAFT_RUNGS:
-            rep = harness(family).run_exactness(rung, seed=0)
-            rates.append(rep.acceptance_rate)
-    assert any(r > 0.0 for r in rates), rates
-    assert any(r < 1.0 for r in rates), rates
-
-
-# ---------------------------------------------------------------------------
-# property 2: cache rollback bit-identity after a REAL round
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("family", FAMILIES)
-@pytest.mark.parametrize("seed", (0, 1))
-def test_rollback_cache_bit_identity(family, seed):
-    res = harness(family).run_rollback("q8_8", seed)
-    assert res["commit_bit_identical"], (
-        f"{family}/seed{seed}: committed caches != sequential-decode caches"
-    )
-    assert res["rejected_restored"]
-
-
-def test_rollback_sweep_includes_real_rejections():
-    """The bit-identity property is only meaningful if some round in
-    the sweep actually rejected drafts; check that across seeds at the
-    cheapest rung at least one rejection occurred per family."""
-    for family in FAMILIES:
-        h = harness(family)
-        assert any(
-            h.run_rollback("q8_8", seed)["had_rejections"] for seed in (0, 1, 2)
-        ), f"{family}: no rejections in 3 seeds — sweep too easy"
-
+from spec_harness import family_config, make_prompts, simulate_acceptance
+from spec_sweep import harness
 
 # ---------------------------------------------------------------------------
 # property 3 (edge): the simulator itself, on hand-built traces
@@ -137,19 +59,8 @@ def test_simulator_hand_built_rounds():
 
 
 # ---------------------------------------------------------------------------
-# k variation + config validation
+# config validation
 # ---------------------------------------------------------------------------
-
-
-def test_k_variation_token_exactness():
-    """k=1 (degenerate: one draft per round) and k=5 must both match
-    k=3's output exactly — k is a throughput knob, not a semantics one."""
-    base = harness("gemma2_2b").run_exactness("q16_16", seed=0)
-    for k in (1, 5):
-        rep = harness("gemma2_2b", k).run_exactness("q16_16", seed=0)
-        assert rep.tokens_ok
-        assert rep.speculative == base.speculative, f"k={k} changed tokens"
-        assert rep.accounting_ok
 
 
 def test_speculative_config_validation():
@@ -175,39 +86,6 @@ def test_generate_rejects_insufficient_headroom():
     dec = h.decoder("q8_8")
     with pytest.raises(ValueError, match="headroom"):
         dec.generate([[1, 2, 3]], max_new=200)
-
-
-# ---------------------------------------------------------------------------
-# EOS semantics
-# ---------------------------------------------------------------------------
-
-
-def test_eos_truncates_like_vanilla():
-    """With an EOS id that actually fires, the speculative stream must
-    stop exactly where vanilla stops — even when the EOS token was
-    committed mid-round with further verified tokens behind it."""
-    h = harness("jamba_v01_52b")
-    rep = h.run_exactness("q8_8", seed=2, max_new=16)
-    ref = rep.vanilla
-    # pick an EOS id that appears in some reference stream (not at the
-    # very start); fall back to a non-appearing id (pure budget stop)
-    eos = None
-    for toks in ref:
-        for t in toks[1:]:
-            eos = t
-            break
-        if eos is not None:
-            break
-    dec = LadderSpeculativeDecoder(
-        h.cfg, h.params,
-        SpeculativeConfig(k=3, draft_level="q8_8", max_len=64, eos_id=eos),
-    )
-    got = dec.generate(make_prompts(h.cfg.vocab, 2), max_new=16)
-    for g, r in zip(got, ref):
-        if eos in r:
-            assert g == r[: r.index(eos) + 1]  # EOS kept, nothing after
-        else:
-            assert g == r
 
 
 # ---------------------------------------------------------------------------

@@ -7,11 +7,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from _reference import teacher_forced
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs import smoke
 from repro.core.precision import Mode
 from repro.data.pipeline import DataConfig, SyntheticLM
-from repro.models import decode_step, init_caches, init_params, prefill_step, train_loss
+from repro.models import decode_step, init_caches, init_params, train_loss
 from repro.runtime.serve import BatchedServer, ServerConfig
 from repro.runtime.train_loop import InjectedFailure, Trainer, TrainerConfig
 
@@ -147,13 +148,7 @@ def test_serving_matches_teacher_forcing():
 
     # teacher-forced reference: repeatedly run prefill on the growing
     # sequence (no cache reuse) and take argmax
-    seq = list(prompt)
-    for _ in range(6):
-        caches = init_caches(cfg, 1, 64)
-        logits, _ = jax.jit(lambda p, t, c: prefill_step(p, t, c, cfg, mode="exact"))(
-            params, jnp.asarray([seq], jnp.int32), caches
-        )
-        seq.append(int(jnp.argmax(logits[0])))
+    seq = teacher_forced(cfg, params, prompt, 6, "exact")
     assert out == seq, (out, seq)
 
 
@@ -181,13 +176,7 @@ def test_serving_decode_consistency_all_families(arch):
     srv = BatchedServer(cfg, params, ServerConfig(max_batch=1, max_len=64, max_new=4))
     out = srv.generate([prompt])[0]
 
-    seq = list(prompt)
-    for _ in range(4):
-        caches = init_caches(cfg, 1, 64)
-        logits, _ = jax.jit(lambda p, t, c: prefill_step(p, t, c, cfg, mode="exact"))(
-            params, jnp.asarray([seq], jnp.int32), caches
-        )
-        seq.append(int(jnp.argmax(logits[0])))
+    seq = teacher_forced(cfg, params, prompt, 4, "exact")
     assert out == seq, (arch, out, seq)
 
 
