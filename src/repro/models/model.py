@@ -191,9 +191,27 @@ def write_cache_slot(pool, single, slot):
 
 
 def _embed(params, tokens, cfg: ModelConfig, extra_embeds=None):
-    # cast BEFORE the gather: the FSDP all-gather of the table (and the
-    # row gather itself) then moves bf16, not the f32 master copy
+    """Training embedding: cast the table BEFORE the gather, so the FSDP
+    all-gather of a sharded table (and the row gather itself) moves
+    bf16, not the f32 master copy.  The serving steps run on one chip,
+    where that cast would rewrite the whole table per dispatch to read a
+    few rows: they gather first (:func:`_embed_rows`)."""
     x = jnp.take(params["embed"].astype(jnp.bfloat16), tokens, axis=0)
+    return _add_stub_prefix(x, cfg, extra_embeds)
+
+
+def _embed_rows(params, tokens, cfg: ModelConfig, extra_embeds=None):
+    """Serving embedding: gather the token rows from the table, then
+    round just those rows to bf16 -- the same bits as :func:`_embed`,
+    since rounding is per element.  The rounding is
+    ``lax.reduce_precision``: the TPU compiler may drop an f32 -> bf16 ->
+    f32 ``astype`` round trip, which the exact rung's upcast would make."""
+    rows = jnp.take(params["embed"], tokens, axis=0)
+    rows = jax.lax.reduce_precision(rows, exponent_bits=8, mantissa_bits=7)
+    return _add_stub_prefix(rows.astype(jnp.bfloat16), cfg, extra_embeds)
+
+
+def _add_stub_prefix(x, cfg: ModelConfig, extra_embeds):
     if extra_embeds is not None and cfg.stub_prefix_len:
         P = cfg.stub_prefix_len
         x = jnp.concatenate(
@@ -390,7 +408,7 @@ def prefill_step(
     """
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    x = _embed(params, tokens, cfg, extra_embeds)
+    x = _embed_rows(params, tokens, cfg, extra_embeds)
     if mode == "exact":
         x = x.astype(jnp.float32)
     x, new_caches = _scan_with_caches(params, x, caches, cfg, positions, mode, constrain, prefill=True)
@@ -430,7 +448,7 @@ def decode_step(
     """
     B = token.shape[0]
     positions = position.reshape(B, 1).astype(jnp.int32)
-    x = _embed(params, token, cfg)
+    x = _embed_rows(params, token, cfg)
     if mode == "exact":
         x = x.astype(jnp.float32)
     if lane_mask is not None:
@@ -472,7 +490,7 @@ def segment_step(
     vanilla f32 decode would have produced).
     """
     B, S = tokens.shape
-    x = _embed(params, tokens, cfg)
+    x = _embed_rows(params, tokens, cfg)
     if mode == "exact":
         x = x.astype(jnp.float32)
     if lane_mask is not None:
