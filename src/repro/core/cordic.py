@@ -87,7 +87,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -717,17 +717,26 @@ def cordic_sigmoid(x):
 # ---------------------------------------------------------------------------
 
 
-def rope_inv_freq_q64(head_dim: int, base: float = 10000.0) -> Tuple[np.ndarray, np.ndarray]:
+def rope_inv_freq_q64(head_dim: int, base: float = 10000.0,
+                      factors: Optional[Sequence[float]] = None) -> Tuple[np.ndarray, np.ndarray]:
     """Per-pair rotary frequency as an exact Q0.64 fraction of a *turn*.
 
-    ``f_j = base**(-2j/d) / (2*pi)`` encoded as (hi, lo) uint32 limbs of
-    ``round(f_j * 2**64)``.  Computed host-side with Python integers.
+    ``f_j = base**(-2j/d) / factors[j] / (2*pi)`` (``factors``: LongRoPE's
+    per-frequency divisors, none by default) encoded as (hi, lo) uint32
+    limbs of ``round(f_j * 2**64)``.  Computed host-side with Python
+    integers, so a frequency that is not a power of the base costs the
+    phase nothing: it stays one exact integer product per position.
     """
     half = head_dim // 2
+    if factors is not None and len(factors) != half:
+        raise ValueError(f"{len(factors)} rope factors for {half} frequency pairs")
     hi = np.zeros((half,), np.uint32)
     lo = np.zeros((half,), np.uint32)
     for j in range(half):
-        turns = (base ** (-2.0 * j / head_dim)) / (2.0 * math.pi)
+        freq = base ** (-2.0 * j / head_dim)
+        if factors is not None:
+            freq = freq / float(factors[j])
+        turns = freq / (2.0 * math.pi)
         q = int(round(turns * float(1 << 64)))
         q = min(q, (1 << 64) - 1)
         hi[j] = (q >> 32) & 0xFFFFFFFF

@@ -516,6 +516,10 @@ def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat1
 
 
 def mla_forward(params, x, cfg: ModelConfig, *, positions, mode="precise", cache=None, prefill: bool = False, constrain=lambda x, kind: x):
+    """Latent attention.  Each path's attention runs under a named scope
+    (``mla_prefill`` for training and prefill, ``mla_decode`` for one
+    token, ``mla_segment`` for a prefill chunk or a verify segment), so a
+    device trace can find it."""
     B, S, _ = x.shape
     m = cfg.mla
     H = cfg.n_heads
@@ -530,7 +534,7 @@ def mla_forward(params, x, cfg: ModelConfig, *, positions, mode="precise", cache
     ckv = rms_norm(kv_a[..., : m.kv_lora_rank], params["kv_norm"], cfg.rms_eps)
     k_rope = kv_a[..., m.kv_lora_rank :]  # (B,S,rope_d) shared across heads
 
-    sin, cos = rope_tables(positions, rope_d, cfg.rope_base, mode)
+    sin, cos = rope_tables(positions, rope_d, cfg.rope_base, mode, cfg.rope_factors)
     q_rope = apply_rope(q_rope, sin, cos)
     k_rope = apply_rope(k_rope[:, :, None, :], sin, cos)[:, :, 0]
 
@@ -538,74 +542,77 @@ def mla_forward(params, x, cfg: ModelConfig, *, positions, mode="precise", cache
     w_uk, w_uv = w_b[..., :nope], w_b[..., nope:]
 
     if cache is None or prefill:
-        k_nope = jnp.einsum("bsr,rhd->bshd", ckv, w_uk).astype(x.dtype)
-        v = jnp.einsum("bsr,rhd->bshd", ckv, w_uv).astype(x.dtype)
-        k_rope_b = jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, rope_d)).astype(x.dtype)
-        k = constrain(jnp.concatenate([k_nope, k_rope_b], axis=-1), "heads4d")
-        v = constrain(v, "heads4d")
-        q_full = constrain(jnp.concatenate([q_nope, q_rope], axis=-1), "heads4d")
-        out = chunked_attention(
-            q_full, k, v,
-            q_positions=positions, causal=True,
-        )
-        new_cache = None
-        if prefill:
-            dt = cache["ckv"].dtype
-            new_cache = {
-                "ckv": jax.lax.dynamic_update_slice_in_dim(
-                    cache["ckv"], ckv.astype(dt), 0, axis=1
-                ),
-                "krope": jax.lax.dynamic_update_slice_in_dim(
-                    cache["krope"], k_rope.astype(dt), 0, axis=1
-                ),
-                "pos": jax.lax.dynamic_update_slice_in_dim(
-                    cache["pos"], positions.astype(jnp.int32), 0, axis=1
-                ),
-            }
+        with jax.named_scope("mla_prefill"):
+            k_nope = jnp.einsum("bsr,rhd->bshd", ckv, w_uk).astype(x.dtype)
+            v = jnp.einsum("bsr,rhd->bshd", ckv, w_uv).astype(x.dtype)
+            k_rope_b = jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, rope_d)).astype(x.dtype)
+            k = constrain(jnp.concatenate([k_nope, k_rope_b], axis=-1), "heads4d")
+            v = constrain(v, "heads4d")
+            q_full = constrain(jnp.concatenate([q_nope, q_rope], axis=-1), "heads4d")
+            out = chunked_attention(
+                q_full, k, v,
+                q_positions=positions, causal=True,
+            )
+            new_cache = None
+            if prefill:
+                dt = cache["ckv"].dtype
+                new_cache = {
+                    "ckv": jax.lax.dynamic_update_slice_in_dim(
+                        cache["ckv"], ckv.astype(dt), 0, axis=1
+                    ),
+                    "krope": jax.lax.dynamic_update_slice_in_dim(
+                        cache["krope"], k_rope.astype(dt), 0, axis=1
+                    ),
+                    "pos": jax.lax.dynamic_update_slice_in_dim(
+                        cache["pos"], positions.astype(jnp.int32), 0, axis=1
+                    ),
+                }
     elif S == 1:
-        # decode: absorbed form — score via latent space, cache stays rank-sized
-        slot = positions[:, 0] % cache["ckv"].shape[1]
-        ckv_c = _store(cache["ckv"], ckv[:, 0], slot)
-        kr_c = _store(cache["krope"], k_rope[:, 0], slot)
-        kp = _store(cache["pos"], positions[:, 0], slot)
-        # q_eff[h] = q_nope[h] @ w_uk[h] : (B,H,rank)
-        q_eff = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)
-        s = jnp.einsum("bhr,blr->bhl", q_eff.astype(jnp.float32), ckv_c.astype(jnp.float32))
-        s = s + jnp.einsum(
-            "bhd,bld->bhl", q_rope[:, 0].astype(jnp.float32), kr_c.astype(jnp.float32)
-        )
-        s = s / math.sqrt(nope + rope_d)
-        valid = (kp[:, None, :] >= 0) & (kp[:, None, :] <= positions[:, 0][:, None, None])
-        s = jnp.where(valid, s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        o_lat = jnp.einsum("bhl,blr->bhr", p, ckv_c.astype(jnp.float32))  # (B,H,rank)
-        out = jnp.einsum("bhr,rhd->bhd", o_lat, w_uv.astype(jnp.float32))
-        out = out[:, None].astype(x.dtype)  # (B,1,H,vd)
-        new_cache = {"ckv": ckv_c, "krope": kr_c, "pos": kp}
+        with jax.named_scope("mla_decode"):
+            # decode: absorbed form — score via latent space, cache stays rank-sized
+            slot = positions[:, 0] % cache["ckv"].shape[1]
+            ckv_c = _store(cache["ckv"], ckv[:, 0], slot)
+            kr_c = _store(cache["krope"], k_rope[:, 0], slot)
+            kp = _store(cache["pos"], positions[:, 0], slot)
+            # q_eff[h] = q_nope[h] @ w_uk[h] : (B,H,rank)
+            q_eff = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)
+            s = jnp.einsum("bhr,blr->bhl", q_eff.astype(jnp.float32), ckv_c.astype(jnp.float32))
+            s = s + jnp.einsum(
+                "bhd,bld->bhl", q_rope[:, 0].astype(jnp.float32), kr_c.astype(jnp.float32)
+            )
+            s = s / math.sqrt(nope + rope_d)
+            valid = (kp[:, None, :] >= 0) & (kp[:, None, :] <= positions[:, 0][:, None, None])
+            s = jnp.where(valid, s, NEG_INF)
+            p = jax.nn.softmax(s, axis=-1)
+            o_lat = jnp.einsum("bhl,blr->bhr", p, ckv_c.astype(jnp.float32))  # (B,H,rank)
+            out = jnp.einsum("bhr,rhd->bhd", o_lat, w_uv.astype(jnp.float32))
+            out = out[:, None].astype(x.dtype)  # (B,1,H,vd)
+            new_cache = {"ckv": ckv_c, "krope": kr_c, "pos": kp}
     else:
-        # segment decode: absorbed form with S queries, per-query masks
-        L = cache["ckv"].shape[1]
-        if S > L:
-            raise ValueError(f"segment length {S} exceeds cache length {L}")
-        ckv_c, kr_c, kp = cache["ckv"], cache["krope"], cache["pos"]
-        for s_i in range(S):
-            slot = positions[:, s_i] % L
-            ckv_c = _store(ckv_c, ckv[:, s_i], slot)
-            kr_c = _store(kr_c, k_rope[:, s_i], slot)
-            kp = _store(kp, positions[:, s_i], slot)
-        q_eff = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk)
-        s = jnp.einsum("bshr,blr->bshl", q_eff.astype(jnp.float32), ckv_c.astype(jnp.float32))
-        s = s + jnp.einsum(
-            "bshd,bld->bshl", q_rope.astype(jnp.float32), kr_c.astype(jnp.float32)
-        )
-        s = s / math.sqrt(nope + rope_d)
-        valid = (kp[:, None, None, :] >= 0) & (kp[:, None, None, :] <= positions[:, :, None, None])
-        s = jnp.where(valid, s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        o_lat = jnp.einsum("bshl,blr->bshr", p, ckv_c.astype(jnp.float32))
-        out = jnp.einsum("bshr,rhd->bshd", o_lat, w_uv.astype(jnp.float32))
-        out = out.astype(x.dtype)  # (B,S,H,vd)
-        new_cache = {"ckv": ckv_c, "krope": kr_c, "pos": kp}
+        with jax.named_scope("mla_segment"):
+            # segment decode: absorbed form with S queries, per-query masks
+            L = cache["ckv"].shape[1]
+            if S > L:
+                raise ValueError(f"segment length {S} exceeds cache length {L}")
+            ckv_c, kr_c, kp = cache["ckv"], cache["krope"], cache["pos"]
+            for s_i in range(S):
+                slot = positions[:, s_i] % L
+                ckv_c = _store(ckv_c, ckv[:, s_i], slot)
+                kr_c = _store(kr_c, k_rope[:, s_i], slot)
+                kp = _store(kp, positions[:, s_i], slot)
+            q_eff = jnp.einsum("bshd,rhd->bshr", q_nope, w_uk)
+            s = jnp.einsum("bshr,blr->bshl", q_eff.astype(jnp.float32), ckv_c.astype(jnp.float32))
+            s = s + jnp.einsum(
+                "bshd,bld->bshl", q_rope.astype(jnp.float32), kr_c.astype(jnp.float32)
+            )
+            s = s / math.sqrt(nope + rope_d)
+            valid = (kp[:, None, None, :] >= 0) & (kp[:, None, None, :] <= positions[:, :, None, None])
+            s = jnp.where(valid, s, NEG_INF)
+            p = jax.nn.softmax(s, axis=-1)
+            o_lat = jnp.einsum("bshl,blr->bshr", p, ckv_c.astype(jnp.float32))
+            out = jnp.einsum("bshr,rhd->bshd", o_lat, w_uv.astype(jnp.float32))
+            out = out.astype(x.dtype)  # (B,S,H,vd)
+            new_cache = {"ckv": ckv_c, "krope": kr_c, "pos": kp}
 
     out = pdot(out.reshape(B, S, H * vd), params["wo"], mode, wq=params.get("wo_q"))
     return out, new_cache

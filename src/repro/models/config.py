@@ -87,6 +87,13 @@ class ModelConfig:
     modality_stub: Optional[str] = None     # None | 'vision' | 'audio'
     stub_prefix_len: int = 0                # patch/frame positions for stubs
     max_seq: int = 32768
+    # MiniCPM-style scalings and LongRoPE; None where the model has none.
+    # Each is applied behind a Python-level branch, so an absent one adds
+    # no operation to the traced program.
+    scale_emb: Optional[float] = None        # embedding rows x scale_emb
+    residual_scale: Optional[float] = None   # every residual branch x this
+    head_divisor: Optional[float] = None     # final-norm output / this, before the head
+    rope_factors: Optional[Tuple[float, ...]] = None  # LongRoPE: angle_i / factor_i
 
     # -- derived ------------------------------------------------------------
 
@@ -194,7 +201,7 @@ def smoke_config(cfg: ModelConfig) -> ModelConfig:
     period = tuple(
         dataclasses.replace(s, window=(8 if s.window else None)) for s in cfg.period
     )
-    return dataclasses.replace(
+    out = dataclasses.replace(
         cfg,
         name=cfg.name + "-smoke",
         d_model=64,
@@ -211,3 +218,9 @@ def smoke_config(cfg: ModelConfig) -> ModelConfig:
         stub_prefix_len=4 if cfg.modality_stub else 0,
         max_seq=64,
     )
+    if cfg.rope_factors:
+        # one factor a smoke rope pair, keeping the published spread (near 1
+        # up to the largest stretch): every k-th, k = published / smoke pairs
+        f, half = cfg.rope_factors, out.rope_dim // 2
+        out = dataclasses.replace(out, rope_factors=tuple(f[i * len(f) // half] for i in range(half)))
+    return out

@@ -360,27 +360,34 @@ def pdot(x, w, mode: str = "precise", wq=None):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("rope_dim", "base", "mode"))
-def rope_tables(positions, rope_dim: int, base: float = 10000.0, mode: str = "precise"):
+@functools.partial(jax.jit, static_argnames=("rope_dim", "base", "mode", "factors"))
+def rope_tables(positions, rope_dim: int, base: float = 10000.0, mode: str = "precise",
+                factors: Optional[Tuple[float, ...]] = None):
     """(… ) int positions -> (…, rope_dim//2) sin/cos tables.
 
     PRECISE: fp32 ``jnp.sin/cos`` of ``pos * inv_freq``.
     FAST: exact Q0.64 phase accumulation + 16-iteration CORDIC
     (core/cordic) — integer-only, and *more accurate* than the fp32
     path at long-context positions (tests/test_cordic.py).
+
+    ``factors`` (LongRoPE, rope_dim//2 values): frequency i is divided
+    by ``factors[i]`` -- on the fast path folded into the host-side
+    Q0.64 frequencies, so the phase stays exact integer arithmetic.
     """
     half = rope_dim // 2
     if is_fast_mode(mode):
         from repro.core.cordic import exact_rope_phase_q16, cordic_sincos_q16, rope_inv_freq_q64
         from repro.core.qformat import Q16_16, from_fixed
 
-        f_hi, f_lo = rope_inv_freq_q64(rope_dim, base)
+        f_hi, f_lo = rope_inv_freq_q64(rope_dim, base, factors)
         theta_q = exact_rope_phase_q16(
             positions[..., None], jnp.asarray(f_hi)[None, :], jnp.asarray(f_lo)[None, :]
         )
         sin_q, cos_q = cordic_sincos_q16(theta_q)
         return from_fixed(sin_q, Q16_16), from_fixed(cos_q, Q16_16)
     inv_freq = (base ** (-2.0 * jnp.arange(half, dtype=jnp.float32) / rope_dim))
+    if factors is not None:
+        inv_freq = inv_freq / jnp.asarray(factors, jnp.float32)
     angle = positions[..., None].astype(jnp.float32) * inv_freq
     return jnp.sin(angle), jnp.cos(angle)
 

@@ -211,6 +211,22 @@ def _embed_rows(params, tokens, cfg: ModelConfig, extra_embeds=None):
     return _add_stub_prefix(rows.astype(jnp.bfloat16), cfg, extra_embeds)
 
 
+def _scale_embed(x, cfg: ModelConfig):
+    """Embedding output times ``cfg.scale_emb`` where the model has one
+    (MiniCPM)."""
+    return x if cfg.scale_emb is None else x * cfg.scale_emb
+
+
+def _serve_embed(params, tokens, cfg: ModelConfig, mode: str, extra_embeds=None):
+    """The serving steps' embedding: :func:`_embed_rows`, upcast to f32 on
+    the exact rung (so the scale below is not rounded to bf16 there),
+    then :func:`_scale_embed`."""
+    x = _embed_rows(params, tokens, cfg, extra_embeds)
+    if mode == "exact":
+        x = x.astype(jnp.float32)
+    return _scale_embed(x, cfg)
+
+
 def _add_stub_prefix(x, cfg: ModelConfig, extra_embeds):
     if extra_embeds is not None and cfg.stub_prefix_len:
         P = cfg.stub_prefix_len
@@ -231,6 +247,13 @@ def _backbone_train(params, x, cfg: ModelConfig, positions, mode, constrain, rem
     fn = jax.checkpoint(body, prevent_cse=False) if remat else body
     (x, aux), _ = jax.lax.scan(fn, (x, jnp.zeros((2,), jnp.float32)), params["periods"])
     return x, aux
+
+
+def _final_norm(params, x, cfg: ModelConfig):
+    """The final RMSNorm, then the head-input divisor where the model has
+    one (MiniCPM: hidden size / dim_model_base)."""
+    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    return x if cfg.head_divisor is None else x / cfg.head_divisor
 
 
 def _lm_head(params, cfg: ModelConfig):
@@ -300,10 +323,10 @@ def train_loss(
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
 
-    x = _embed(params, tokens, cfg, batch.get("extra_embeds"))
+    x = _scale_embed(_embed(params, tokens, cfg, batch.get("extra_embeds")), cfg)
     x = constrain(x, "residual")
     x, aux = _backbone_train(params, x, cfg, positions, mode, constrain, remat)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    x = _final_norm(params, x, cfg)
 
     loss_s, z_s, cnt = _chunked_ce(x, _lm_head(params, cfg), labels, mask, cfg, mode=mode)
     ce = loss_s / jnp.maximum(cnt, 1.0)
@@ -408,11 +431,9 @@ def prefill_step(
     """
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    x = _embed_rows(params, tokens, cfg, extra_embeds)
-    if mode == "exact":
-        x = x.astype(jnp.float32)
+    x = _serve_embed(params, tokens, cfg, mode, extra_embeds)
     x, new_caches = _scan_with_caches(params, x, caches, cfg, positions, mode, constrain, prefill=True)
-    x = rms_norm(x[:, -1:], params["final_norm"], cfg.rms_eps)
+    x = _final_norm(params, x[:, -1:], cfg)
     head_dt = jnp.float32 if mode == "exact" else jnp.bfloat16
     logits = jnp.dot(
         x[:, 0].astype(head_dt),
@@ -448,13 +469,11 @@ def decode_step(
     """
     B = token.shape[0]
     positions = position.reshape(B, 1).astype(jnp.int32)
-    x = _embed_rows(params, token, cfg)
-    if mode == "exact":
-        x = x.astype(jnp.float32)
+    x = _serve_embed(params, token, cfg, mode)
     if lane_mask is not None:
         x = x * lane_mask.astype(x.dtype)[:, None, None]
     x, new_caches = _scan_with_caches(params, x, caches, cfg, positions, mode, constrain, prefill=False)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    x = _final_norm(params, x, cfg)
     head_dt = jnp.float32 if mode == "exact" else jnp.bfloat16
     logits = jnp.dot(
         x[:, 0].astype(head_dt),
@@ -490,16 +509,14 @@ def segment_step(
     vanilla f32 decode would have produced).
     """
     B, S = tokens.shape
-    x = _embed_rows(params, tokens, cfg)
-    if mode == "exact":
-        x = x.astype(jnp.float32)
+    x = _serve_embed(params, tokens, cfg, mode)
     if lane_mask is not None:
         x = x * lane_mask.astype(x.dtype)[:, None, None]
     x, new_caches, seg_aux = _scan_with_caches(
         params, x, caches, cfg, positions.astype(jnp.int32), mode, constrain,
         prefill=False, collect_aux=True,
     )
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+    x = _final_norm(params, x, cfg)
     head_dt = jnp.float32 if mode == "exact" else jnp.bfloat16
     logits = jnp.einsum(
         "bsd,dv->bsv",

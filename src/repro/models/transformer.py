@@ -74,6 +74,12 @@ def init_period_cache(
     return out
 
 
+def _scaled_branch(h, cfg: ModelConfig):
+    """A residual branch's output, times ``cfg.residual_scale`` where
+    the model has one (MiniCPM: scale_depth / sqrt(published depth))."""
+    return h if cfg.residual_scale is None else h * cfg.residual_scale
+
+
 def period_forward(
     params: dict,
     x: jnp.ndarray,
@@ -119,7 +125,7 @@ def period_forward(
             )
             if seg_aux is not None:
                 seg_aux[f"pos{i}"] = layer_aux
-        x = constrain(x + h, "residual")
+        x = constrain(x + _scaled_branch(h, cfg), "residual")
 
         if "ffn" in p:
             if spec.ffn == "moe":
@@ -127,7 +133,7 @@ def period_forward(
                 aux = aux + a
             else:
                 h = swiglu_mlp(p["ffn"], x, mode, cfg.rms_eps)
-            x = constrain(x + h, "residual")
+            x = constrain(x + _scaled_branch(h, cfg), "residual")
 
         if new_caches is not None:
             new_caches[f"pos{i}"] = c

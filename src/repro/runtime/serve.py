@@ -437,6 +437,11 @@ class ContinuousBatchingServer:
             "host_syncs_total", "device->host synchronizations",
             labelnames=("kind",))
         self._m_active = reg.gauge("active_slots", "slots bound to a request")
+        self._m_attn_rows = reg.counter(
+            "attn_rows_total",
+            "cache rows decode passes' latent-attention layers compute over "
+            "(batch lanes x view length) and attend live (position + 1 a member lane)",
+            labelnames=("kind", "rows"))
         self._m_arb = reg.counter(
             "arbiter_switches_total", "slot-arbiter rung switches",
             labelnames=("arbiter", "cause"))
@@ -448,6 +453,21 @@ class ContinuousBatchingServer:
         self._m_req_latency = reg.histogram(
             "request_latency_seconds", "admission->finish wall time (s)",
             buckets=tb)
+
+    def _count_attn_rows(self, lanes: np.ndarray) -> None:
+        """``attn_rows_total{kind="mla"}`` for one decode pass whose member
+        lanes are set in ``lanes``, summed over the model's MLA layers:
+        ``computed``, the rows its attention runs over (every lane of the
+        batch -- a pass computes masked lanes too -- x the view length);
+        ``live``, the rows its member lanes attend: the decoded token's
+        position + 1 each, which is the scheduler's next position."""
+        n_mla = self.cfg.n_periods * sum(s.kind == "mla" for s in self.cfg.period)
+        if not n_mla:
+            return
+        live = sum(self.scheduler.position(int(s)) for s in np.nonzero(lanes)[0])
+        computed = len(lanes) * self.scfg.max_len
+        self._m_attn_rows.inc(n_mla * computed, kind="mla", rows="computed")
+        self._m_attn_rows.inc(n_mla * live, kind="mla", rows="live")
 
     def _make_switch_hook(self, arbiter_name: str, rung_names):
         """Observer for :attr:`SlotArbiter.on_switch`: promotes every
@@ -1152,11 +1172,14 @@ class ContinuousBatchingServer:
                                     self._gen_buf, self._gen_count, self._health,
                                 )
                         self._m_level_passes.inc(level=lv)
+                        self._count_attn_rows(van_now)
                     else:
                         # mixed levels: one pool pass per level, mask-merged
                         logits = self._zero_logits
                         for li in present:
-                            mask = jnp.asarray(van_now & (levels == li))
+                            members = van_now & (levels == li)
+                            self._count_attn_rows(members)
+                            mask = jnp.asarray(members)
                             lv = self.level_names[li]
                             with tel.span("level-pass",
                                           args={"level": lv} if tel_on else None):
