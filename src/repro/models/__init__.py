@@ -14,6 +14,7 @@ from repro.models.config import (
 from repro.models.model import (
     cache_layout,
     commit_segment,
+    decode_rows,
     decode_step,
     init_caches,
     init_params,
@@ -24,4 +25,5 @@ from repro.models.model import (
     train_loss,
     truncate_cache_slot,
     write_cache_slot,
+    write_rows,
 )

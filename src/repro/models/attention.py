@@ -341,7 +341,10 @@ def attention_forward(
 
     cache=None             -> training forward (no cache out)
     cache given, prefill   -> chunked attention + cache populated [0:S)
-    cache given, S==1      -> single-token decode against the cache
+    cache given, S==1      -> single-token decode against the cache;
+                              returns the new ROWS (B, ...), not a cache
+    cache given, S>1       -> segment decode; returns the cache with the
+                              segment written
     """
     B, S, _ = x.shape
     h = rms_norm(x, params["norm"], cfg.rms_eps)
@@ -369,32 +372,26 @@ def attention_forward(
         if prefill:
             new_cache = _prefill_cache(cache, k, v, positions, layer.window)
     elif S == 1:
-        L = cache["k"].shape[1]
-        slot = positions[:, 0] % L  # rolling for SWA; L==max_len handles full
-        quantized = "k_exp" in cache
-        if quantized:
+        # decode: the new row is selected in at its slot where the
+        # attention reads the cache, and only the rows come back -- the
+        # caller writes them (``repro.models.write_rows``, the paged
+        # commit), so no layer produces a whole new cache
+        slot = positions[:, 0] % cache["k"].shape[1]  # rolling for SWA
+        if "k_exp" in cache:
             qk, e_k = _q8(k[:, 0], axes=(2,))            # exps (B, KV)
             qv, e_v = _q8(v[:, 0], axes=(2,))
-            k_cache = _store(cache["k"], qk, slot)
-            v_cache = _store(cache["v"], qv, slot)
-            ek_c = _store(cache["k_exp"], e_k, slot)
-            ev_c = _store(cache["v_exp"], e_v, slot)
+            new_cache = {"k": qk, "v": qv, "pos": positions[:, 0],
+                         "k_exp": e_k, "v_exp": e_v}
         else:
-            k_cache = _store(cache["k"], k[:, 0], slot)
-            v_cache = _store(cache["v"], v[:, 0], slot)
-            ek_c = ev_c = None
-        kp = _store(cache["pos"], positions[:, 0], slot)
+            new_cache = {"k": k[:, 0], "v": v[:, 0], "pos": positions[:, 0]}
+        seen = {name: _with_row(cache[name], row, slot) for name, row in new_cache.items()}
         out = decode_attention(
-            q, k_cache, v_cache, kp,
+            q, seen["k"], seen["v"], seen["pos"],
             q_position=positions[:, 0],
             window=layer.window,
             cap=cfg.attn_softcap,
-            k_exp=ek_c, v_exp=ev_c,
+            k_exp=seen.get("k_exp"), v_exp=seen.get("v_exp"),
         )
-        new_cache = {"k": k_cache, "v": v_cache, "pos": kp}
-        if quantized:
-            new_cache["k_exp"] = ek_c
-            new_cache["v_exp"] = ev_c
     else:
         # segment decode (speculative verify): S tokens against the
         # cache with per-query causal masks.  Requires S <= L so the
@@ -501,6 +498,15 @@ def _store(buf, val, slot):
     return buf * (1 - oh) + oh * val[:, None]
 
 
+def _with_row(buf, val, slot):
+    """buf (B, L, ...) as read with val (B, ...) at per-batch slot (B,):
+    the same values as :func:`_store`, as a select the attention read
+    consumes (dtypes promote as the blend's do, so an f32 row is read
+    unrounded from a bf16 cache)."""
+    at = jnp.arange(buf.shape[1], dtype=slot.dtype) == slot[:, None]  # (B, L)
+    return jnp.where(at.reshape(at.shape + (1,) * (buf.ndim - 2)), val[:, None], buf)
+
+
 # ---------------------------------------------------------------------------
 # MLA (MiniCPM3 / deepseek-family latent attention)
 # ---------------------------------------------------------------------------
@@ -516,7 +522,8 @@ def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat1
 
 
 def mla_forward(params, x, cfg: ModelConfig, *, positions, mode="precise", cache=None, prefill: bool = False, constrain=lambda x, kind: x):
-    """Latent attention.  Each path's attention runs under a named scope
+    """Latent attention; decode (S == 1) returns the new rows, as
+    :func:`attention_forward` does.  Each path's attention runs under a named scope
     (``mla_prefill`` for training and prefill, ``mla_decode`` for one
     token, ``mla_segment`` for a prefill chunk or a verify segment), so a
     device trace can find it."""
@@ -571,9 +578,9 @@ def mla_forward(params, x, cfg: ModelConfig, *, positions, mode="precise", cache
         with jax.named_scope("mla_decode"):
             # decode: absorbed form — score via latent space, cache stays rank-sized
             slot = positions[:, 0] % cache["ckv"].shape[1]
-            ckv_c = _store(cache["ckv"], ckv[:, 0], slot)
-            kr_c = _store(cache["krope"], k_rope[:, 0], slot)
-            kp = _store(cache["pos"], positions[:, 0], slot)
+            new_cache = {"ckv": ckv[:, 0], "krope": k_rope[:, 0], "pos": positions[:, 0]}
+            ckv_c, kr_c, kp = (_with_row(cache[n], new_cache[n], slot)
+                               for n in ("ckv", "krope", "pos"))
             # q_eff[h] = q_nope[h] @ w_uk[h] : (B,H,rank)
             q_eff = jnp.einsum("bhd,rhd->bhr", q_nope[:, 0], w_uk)
             s = jnp.einsum("bhr,blr->bhl", q_eff.astype(jnp.float32), ckv_c.astype(jnp.float32))
@@ -587,7 +594,6 @@ def mla_forward(params, x, cfg: ModelConfig, *, positions, mode="precise", cache
             o_lat = jnp.einsum("bhl,blr->bhr", p, ckv_c.astype(jnp.float32))  # (B,H,rank)
             out = jnp.einsum("bhr,rhd->bhd", o_lat, w_uv.astype(jnp.float32))
             out = out[:, None].astype(x.dtype)  # (B,1,H,vd)
-            new_cache = {"ckv": ckv_c, "krope": kr_c, "pos": kp}
     else:
         with jax.named_scope("mla_segment"):
             # segment decode: absorbed form with S queries, per-query masks

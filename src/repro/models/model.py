@@ -7,7 +7,9 @@ Public step functions (all pure, jit/pjit-ready):
                     losses, z-loss; remat over periods.
 ``prefill_step``  — segment forward, returns last-position logits and
                     populated caches.
-``decode_step``   — one token against caches.
+``decode_rows``   — one token against caches, returning the rows it
+                    adds (``write_rows`` writes them).
+``decode_step``   — one token against caches, returning the caches.
 
 Modality stubs (phi-3-vision, musicgen): ``extra_embeds`` (B, P, d) are
 pre-computed patch/frame embeddings added onto the first P token
@@ -35,7 +37,9 @@ __all__ = [
     "cache_layout",
     "train_loss",
     "prefill_step",
+    "decode_rows",
     "decode_step",
+    "write_rows",
     "segment_step",
     "commit_segment",
     "reset_cache_slot",
@@ -348,7 +352,10 @@ def train_loss(
 def _scan_with_caches(params, x, caches, cfg, positions, mode, constrain, *,
                       prefill, collect_aux: bool = False):
     """Scan periods with the stacked cache in the CARRY, updated in
-    place via dynamic_update_index — ONE cache buffer end to end.
+    place via dynamic_update_index — ONE cache buffer end to end, for
+    the steps that write whole segments (prefill, segment decode).
+    Single-token decode writes one row a layer and scans with
+    :func:`_scan_rows` instead.
 
     (Passing caches as scan xs/ys double-buffers them: the stacked ys
     output is distinct from the xs input, costing a full extra cache
@@ -385,6 +392,57 @@ def _scan_with_caches(params, x, caches, cfg, positions, mode, constrain, *,
     if collect_aux:
         return x, new_caches, aux
     return x, new_caches
+
+
+def _scan_rows(params, x, caches, cfg, positions, mode, constrain):
+    """The single-token decode scan: the stacked cache is a read-only
+    scan operand, and each period's new rows (one per position-indexed
+    cache and lane, and each SSM layer's new state) are the scan's
+    outputs, leaves ``(n_periods, B, ...)`` in the cache's dtypes (as
+    the cache stores them, so every precision rung returns the same
+    types).  Nothing cache-sized is written, so the outputs cost a row
+    per lane a layer, not a cache."""
+
+    def body(h, xs):
+        period_params, cache_i = xs
+        h2, rows, _ = period_forward(
+            period_params, h, cfg,
+            positions=positions, mode=mode, caches=cache_i, constrain=constrain,
+        )
+        return h2, jax.tree.map(lambda r, c: r.astype(c.dtype), rows, cache_i)
+
+    return jax.lax.scan(body, x, (params["periods"], caches))
+
+
+def write_rows(caches, rows, position, cfg: ModelConfig, mask=None):
+    """Write :func:`decode_rows`' rows into a stacked cache (leaves
+    ``(n_periods, B, ...)``): lane ``b``'s row of a position-indexed
+    cache lands at ``position[b] % L`` (one ``.at[:, b, slot]`` scatter
+    a leaf); SSM leaves take the new state.  ``mask`` (B,) bool: lanes
+    off it keep their cache bit for bit (their scatter is dropped)."""
+    B = position.shape[0]
+    lanes = jnp.arange(B)
+    if mask is not None:
+        lanes = jnp.where(mask, lanes, B)  # out of range -> dropped
+    out = {}
+    for i, spec in enumerate(cfg.period):
+        key = f"pos{i}"
+        c, r = caches[key], rows[key]
+        if spec.kind in ("attn", "mla"):
+            slot = position % c["pos"].shape[2]
+            out[key] = {
+                n: c[n].at[:, lanes, slot].set(r[n].astype(c[n].dtype), mode="drop")
+                for n in c
+            }
+        elif mask is None:
+            out[key] = {n: r[n].astype(c[n].dtype) for n in c}
+        else:
+            out[key] = {
+                n: jnp.where(mask.reshape((1, -1) + (1,) * (c[n].ndim - 2)),
+                             r[n].astype(c[n].dtype), c[n])
+                for n in c
+            }
+    return out
 
 
 def _exact_at_highest(step):
@@ -444,7 +502,7 @@ def prefill_step(
 
 
 @_exact_at_highest
-def decode_step(
+def decode_rows(
     params,
     token,
     position,
@@ -454,7 +512,15 @@ def decode_step(
     constrain: Constrain = _id,
     lane_mask=None,
 ):
-    """token (B,1) at scalar-per-batch ``position`` (B,) -> (logits, caches').
+    """token (B,1) at scalar-per-batch ``position`` (B,) -> (logits, rows).
+
+    ``rows`` has the cache tree's keys with leaves ``(n_periods, B,
+    ...)``: the one row each position-indexed cache gains (it lands at
+    ``position % L``) and each SSM layer's new state.  Each layer
+    attends over its cache with its new row selected in at that slot,
+    so the logits are :func:`decode_step`'s bit for bit; the caches are
+    only read.  The serving steps hand the rows to the cache's commit
+    (:func:`write_rows`, ``PagedCachePool.commit_rows``).
 
     mode="exact": see :func:`prefill_step` — the serving-consistency
     f32 path.
@@ -472,7 +538,7 @@ def decode_step(
     x = _serve_embed(params, token, cfg, mode)
     if lane_mask is not None:
         x = x * lane_mask.astype(x.dtype)[:, None, None]
-    x, new_caches = _scan_with_caches(params, x, caches, cfg, positions, mode, constrain, prefill=False)
+    x, rows = _scan_rows(params, x, caches, cfg, positions, mode, constrain)
     x = _final_norm(params, x, cfg)
     head_dt = jnp.float32 if mode == "exact" else jnp.bfloat16
     logits = jnp.dot(
@@ -480,7 +546,27 @@ def decode_step(
         _lm_head(params, cfg).astype(head_dt),
         preferred_element_type=jnp.float32,
     )
-    return softcap(logits, cfg.final_softcap, mode), new_caches
+    return softcap(logits, cfg.final_softcap, mode), rows
+
+
+def decode_step(
+    params,
+    token,
+    position,
+    caches,
+    cfg: ModelConfig,
+    mode: str = "precise",
+    constrain: Constrain = _id,
+    lane_mask=None,
+):
+    """token (B,1) at scalar-per-batch ``position`` (B,) -> (logits,
+    caches'): :func:`decode_rows`, then :func:`write_rows` on every
+    lane."""
+    logits, rows = decode_rows(
+        params, token, position, caches, cfg, mode=mode, constrain=constrain,
+        lane_mask=lane_mask,
+    )
+    return logits, write_rows(caches, rows, position.reshape(-1).astype(jnp.int32), cfg)
 
 
 @_exact_at_highest

@@ -93,6 +93,8 @@ def period_forward(
     seg_aux: Optional[dict] = None,
 ) -> Tuple[jnp.ndarray, Optional[dict], jnp.ndarray]:
     """Apply one period. Returns (x, new_caches, aux_losses (2,)).
+    Single-token decode (S == 1, not prefill): ``new_caches`` holds each
+    layer's new rows (leaves (B, ...)), not caches.
 
     ``seg_aux``: mutable dict for segment-decode rollback state.  When
     given (speculative verify), each SSM layer records its per-position

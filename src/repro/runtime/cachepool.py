@@ -18,11 +18,12 @@ vLLM-style paging:
   mirror is an ordinary jit argument: table CONTENT changes never
   retrace.
 * **gather/scatter adapters** — ``device_view`` gathers pages into the
-  exact logical ``(n_periods, B, L, ...)`` layout ``decode_step`` /
+  exact logical ``(n_periods, B, L, ...)`` layout ``decode_rows`` /
   ``segment_step`` already consume (bit-identical values), and
-  ``commit_rows`` scatters back ONLY the rows a step wrote (decode: 1
-  row; speculative verify: k+1 rows whose rejected entries carry the
-  rolled-back ``before`` bits — page-granular restore stays bit-exact).
+  ``commit_rows`` scatters back ONLY the rows a step adds (decode: the
+  1 row a lane ``decode_rows`` returns; speculative verify: k+1 rows
+  cut from its view, whose rejected entries carry the rolled-back
+  ``before`` bits — page-granular restore stays bit-exact).
   Cumulative SSM state is O(1) per slot and stays slot-contiguous
   inside the same state tree.
 * **prefix sharing** — full pages are keyed by a SHA-256 chain over
@@ -528,42 +529,53 @@ class PagedCachePool:
             view[key] = state["slot"][key]
         return view
 
-    def commit_rows(self, state, tables, view, pos, mask, n_rows: int = 1):
-        """Scatter ``n_rows`` decode-step rows per lane from a logical
-        view back into the pages (masked lanes write nothing), and fold
-        the cumulative SSM leaves under the same mask.  Row ``j`` of
-        lane ``b`` lives at logical position ``pos[b] + j`` (mod L for
-        rolling windows); for speculative verify the view's rejected
-        rows already carry the rolled-back ``before`` bits, so the
-        scatter IS the page-granular restore."""
+    def commit_rows(self, state, tables, rows, pos, mask):
+        """Scatter one row per lane into the pages (masked lanes write
+        nothing), and fold the cumulative SSM leaves under the same
+        mask.  ``rows`` is a decode step's rows
+        (:func:`repro.models.decode_rows`): leaves ``(n_periods, B,
+        ...)``, lane ``b``'s row landing at logical position ``pos[b]``
+        (mod L for rolling windows); for the SSM keys, the new state."""
         ps = self.page_size
         pages = {k: dict(v) for k, v in state["pages"].items()}
         for gk, g in self.groups.items():
             L, NP = g["L"], g["alloc"].n_pages
-            t = tables[gk]  # (B, nb)
-            for j in range(n_rows):
-                idx = (pos + j) % L                     # (B,) logical row
-                block = idx // ps
-                pid = jnp.take_along_axis(t, block[:, None], axis=1)[:, 0]
-                pid = jnp.where(mask, pid, NP)          # masked -> dropped
-                off = idx % ps
-                for key in g["keys"]:
-                    for name, arr in pages[key].items():
-                        v = view[key][name]             # (P, B, L, *tail)
-                        ir = idx.reshape((1, -1, 1) + (1,) * (v.ndim - 3))
-                        row = jnp.take_along_axis(v, ir, axis=2)[:, :, 0]
-                        pages[key][name] = arr.at[:, pid, off].set(
-                            row.astype(arr.dtype), mode="drop"
-                        )
+            idx = pos % L                           # (B,) logical row
+            block = idx // ps
+            pid = jnp.take_along_axis(tables[gk], block[:, None], axis=1)[:, 0]
+            pid = jnp.where(mask, pid, NP)          # masked -> dropped
+            off = idx % ps
+            for key in g["keys"]:
+                for name, arr in pages[key].items():
+                    pages[key][name] = arr.at[:, pid, off].set(
+                        rows[key][name].astype(arr.dtype), mode="drop"
+                    )
         slot = {}
         for key in self.slot_keys:
             slot[key] = {}
             for name, arr in state["slot"][key].items():
                 m = mask.reshape((1, -1) + (1,) * (arr.ndim - 2))
                 slot[key][name] = jnp.where(
-                    m, view[key][name].astype(arr.dtype), arr
+                    m, rows[key][name].astype(arr.dtype), arr
                 )
         return {"pages": pages, "slot": slot}
+
+    def view_rows(self, view, pos):
+        """Cut the rows at logical position ``pos`` (B,) (mod L) out of a
+        gathered view, in :meth:`commit_rows`' form (the SSM leaves
+        whole).  Speculative verify commits its k+1 segment rows this
+        way: the view's rejected rows already carry the rolled-back
+        ``before`` bits, so the scatter IS the page-granular restore."""
+        rows = {}
+        for g in self.groups.values():
+            for key in g["keys"]:
+                rows[key] = {}
+                for name, v in view[key].items():   # (P, B, L, *tail)
+                    ir = (pos % g["L"]).reshape((1, -1, 1) + (1,) * (v.ndim - 3))
+                    rows[key][name] = jnp.take_along_axis(v, ir, axis=2)[:, :, 0]
+        for key in self.slot_keys:
+            rows[key] = view[key]
+        return rows
 
     def slot_view(self, state, slot_tables, slot):
         """One slot's logical cache as a ``(n_periods, 1, ...)`` tree

@@ -74,10 +74,12 @@ from repro.core.arbiter import SlotArbiter
 from repro.core.precision import MathEngine, Mode, PrecisionLevel
 from repro.models import (
     commit_segment,
+    decode_rows,
     decode_step,
     init_caches,
     prefill_step,
     segment_step,
+    write_rows,
 )
 from repro.models.config import ModelConfig
 from repro.models.layers import attach_quantized_weights
@@ -542,10 +544,11 @@ class ContinuousBatchingServer:
             # lane_mask zeroes non-member lanes so a pass's input tensor
             # (and therefore the FAST path's per-tensor activation
             # exponents) is independent of the other slots' contents —
-            # the slot-isolation contract (see models.decode_step).
+            # the slot-isolation contract (see models.decode_rows).  The
+            # step returns the rows it adds; the caller commits them.
             def fn(params, tok, pos, caches, lane_mask):
                 self._m_retrace.inc(step="decode")
-                return decode_step(
+                return decode_rows(
                     params, tok, pos, caches, cfg, mode=mode, lane_mask=lane_mask
                 )
             return fn
@@ -558,13 +561,6 @@ class ContinuousBatchingServer:
         )
         pre_disp, _ = self.engine.switched("prefill", levels=self.level_names)
         dec_disp, _ = self.engine.switched("decode", levels=self.level_names)
-
-        def merge_caches(old, new, mask):
-            """Keep ``new`` cache rows only where ``mask`` is set."""
-            def leaf(o, n):
-                m = mask.reshape((1, -1) + (1,) * (n.ndim - 2))
-                return jnp.where(m, n.astype(o.dtype), o)
-            return jax.tree.map(leaf, old, new)
 
         def mask_cache_view(caches, mask):
             """Non-member lanes see a PRISTINE cache: zero payloads,
@@ -600,11 +596,11 @@ class ContinuousBatchingServer:
             mixed-batch path): non-member lanes are zeroed at the input
             AND see a pristine cache view, so members compute exactly
             as if the other levels' slots were empty; cache rows and
-            logits merge only where ``mask`` is set."""
+            logits are written only where ``mask`` is set."""
             self._m_retrace.inc(step="pool_pass")
             view = mask_cache_view(caches, mask)
-            logits, new_caches = dec_disp(level_idx, params, tok, pos, view, mask)
-            caches = merge_caches(caches, new_caches, mask)
+            logits, rows = dec_disp(level_idx, params, tok, pos, view, mask)
+            caches = write_rows(caches, rows, pos, cfg, mask)
             logits_acc = jnp.where(mask[:, None], logits, logits_acc)
             return logits_acc, caches
 
@@ -660,15 +656,15 @@ class ContinuousBatchingServer:
                  gen_buf, gen_count, health):
             """Fused single-level decode step: pool pass + sampling +
             ring/health update in ONE dispatch, composed from the same
-            ``finish``/``step_update``/``merge_caches`` bodies the
+            ``finish``/``step_update``/``write_rows`` bodies the
             mixed-level path jits separately.  The hot path when all
             active slots share a level (homogeneous traffic); its
             masked lanes are only EMPTY slots, whose cache rows the
             eviction reset already zeroed, so no pristine view is
             needed here."""
             self._m_retrace.inc(step="tick")
-            logits, new_caches = dec_disp(level_idx, params, tok[:, None], pos, caches, mask)
-            caches = merge_caches(caches, new_caches, mask)
+            logits, rows = dec_disp(level_idx, params, tok[:, None], pos, caches, mask)
+            caches = write_rows(caches, rows, pos, cfg, mask)
             new_tok, hv = finish(logits, key)
             gen_buf, gen_count, tok, pos, health = step_update(
                 gen_buf, gen_count, tok, pos, health, new_tok, hv, mask
@@ -769,15 +765,15 @@ class ContinuousBatchingServer:
         def tick_p(level_idx, params, tok, pos, state, tables, mask, key,
                    gen_buf, gen_count, health):
             """Paged homogeneous-level decode: gather -> fused step ->
-            scatter the ONE row each active lane wrote.  Masked lanes
+            scatter the ONE row each active lane adds.  Masked lanes
             are only empty slots here (zero tables -> pristine gather),
             mirroring the contiguous ``tick``."""
             self._m_retrace.inc(step="tick")
             view = pool.device_view(state, tables)
-            logits, new_view = dec_disp(
+            logits, rows = dec_disp(
                 level_idx, params, tok[:, None], pos, view, mask
             )
-            state = pool.commit_rows(state, tables, new_view, pos, mask)
+            state = pool.commit_rows(state, tables, rows, pos, mask)
             new_tok, hv = finish(logits, key)
             gen_buf, gen_count, tok, pos, health = step_update(
                 gen_buf, gen_count, tok, pos, health, new_tok, hv, mask
@@ -794,8 +790,8 @@ class ContinuousBatchingServer:
             at the scatter."""
             self._m_retrace.inc(step="pool_pass")
             view = mask_cache_view(pool.device_view(state, tables), mask)
-            logits, new_view = dec_disp(level_idx, params, tok, pos, view, mask)
-            state = pool.commit_rows(state, tables, new_view, pos, mask)
+            logits, rows = dec_disp(level_idx, params, tok, pos, view, mask)
+            state = pool.commit_rows(state, tables, rows, pos, mask)
             logits_acc = jnp.where(mask[:, None], logits, logits_acc)
             return logits_acc, state
 
@@ -820,9 +816,10 @@ class ContinuousBatchingServer:
                 preds, n_commit, view, new_tok, new_pos, finite, amp = verify_j(
                     params, tok, pos, drafts, view, mask
                 )
-                state = pool.commit_rows(
-                    state, tables, view, pos, mask, n_rows=k + 1
-                )
+                for j in range(k + 1):
+                    state = pool.commit_rows(
+                        state, tables, pool.view_rows(view, pos + j), pos + j, mask
+                    )
                 return preds, n_commit, state, new_tok, new_pos, finite, amp
 
             self._spec_verify_p = jax.jit(spec_verify_p, donate_argnums=(4,))

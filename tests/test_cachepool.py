@@ -310,7 +310,7 @@ def test_paged_commit_rows_masked_lane_untouched():
     poked = jax.tree.map(lambda l: l + 7 if l.dtype != jnp.int32 else l + 1,
                          view)
     pos = jnp.zeros((2,), jnp.int32)
-    state2 = pool.commit_rows(state, tables, poked,
+    state2 = pool.commit_rows(state, tables, pool.view_rows(poked, pos),
                               pos, jnp.asarray([True, False]))
     v2 = pool.device_view(state2, tables)
     for keyname, node in v2.items():
@@ -318,6 +318,34 @@ def test_paged_commit_rows_masked_lane_untouched():
             a, b = np.asarray(leaf), np.asarray(view[keyname][name])
             # lane 1 bit-identical; lane 0 row 0 changed
             assert (a[:, 1] == b[:, 1]).all(), (keyname, name)
+            assert (a[:, 0, 0] != b[:, 0, 0]).all(), (keyname, name)
+
+
+@pytest.mark.parametrize("arch", ["deepseek-7b", "gemma2-2b"])
+def test_paged_commit_rows_lands_at_pos_mod_L(arch):
+    """A decode step's rows: an unmasked lane's row lands at ``pos % L``
+    of each page group (gemma2: its 8-row window wraps, its full
+    layers do not) and nothing else of the slot moves; a masked lane's
+    rows are dropped."""
+    cfg, pool, state = _mk_pool(arch)
+    pos = jnp.asarray([13, 29], jnp.int32)
+    for slot in (0, 1):
+        state = pool.ensure_rows(state, slot, 0, 31)
+    tables = pool.device_tables()
+    view = pool.device_view(state, tables)
+    rng = np.random.default_rng(5)
+    rows = jax.tree.map(  # distinct values, the shape of a row per lane
+        lambda l: jnp.asarray(rng.integers(1, 100, l.shape[:2] + l.shape[3:]), l.dtype),
+        view)
+    state2 = pool.commit_rows(state, tables, rows, pos, jnp.asarray([True, False]))
+    v2 = pool.device_view(state2, tables)
+    for keyname, node in v2.items():
+        for name, leaf in node.items():
+            a, b = np.array(leaf), np.asarray(view[keyname][name])
+            slot = 13 % a.shape[2]
+            assert (a[:, 0, slot] == np.asarray(rows[keyname][name])[:, 0]).all()
+            a[:, 0, slot] = b[:, 0, slot]
+            assert (a == b).all(), (keyname, name)   # nothing else moved
 
 
 def test_paged_copy_on_write():
