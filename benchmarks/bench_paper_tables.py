@@ -278,20 +278,21 @@ def _decode_tokens_per_s(max_new: int = 12):
     from repro.configs.gemma2_2b import CONFIG
     from repro.models import init_params
     from repro.models.config import smoke_config
-    from repro.runtime.serve import BatchedServer, ServerConfig
+    from repro.runtime.serve import ContinuousBatchingServer, ServingConfig
 
     cfg = smoke_config(CONFIG)
     params = init_params(cfg, jax.random.PRNGKey(0))
     prompts = [[1, 2, 3, 4], [5, 6, 7]]
     out = {}
     for label, level in (("fast", "q16_16"), ("precise", "f32")):
-        srv = BatchedServer(
+        # page size 8 divides the smoke config's 8-row sliding window
+        srv = ContinuousBatchingServer(
             cfg, params,
-            ServerConfig(max_batch=2, max_len=64, max_new=max_new, start_mode=level),
+            ServingConfig(n_slots=2, max_len=64, page_size=8, default_level=level),
         )
-        srv.generate(prompts)  # warm (compile both steps)
+        srv.generate(prompts, max_new=max_new)  # warm (compile every step)
         t0 = time.perf_counter()
-        outs = srv.generate(prompts)
+        outs = srv.generate(prompts, max_new=max_new)
         dt = time.perf_counter() - t0
         new_toks = sum(len(o) - len(p) for o, p in zip(outs, prompts))
         out[label] = new_toks / dt

@@ -1,21 +1,14 @@
-"""Serving-throughput benchmark: static lock-step batching vs the
-continuous-batching engine on a MIXED workload (mixed prompt lengths,
-mixed per-request decode budgets) — the traffic shape the scheduler
-exists for.
+"""Serving-throughput benchmark on the CPU: the continuous-batching
+engine on a MIXED workload (mixed prompt lengths, mixed per-request
+decode budgets) — the traffic shape the scheduler exists for — and the
+paged pool's prefix sharing on a long-prompt shared-prefix workload.
 
-Both servers run the same smoke model at the same (FAST) level, so the
-comparison isolates the *scheduling* win: the static server decodes
-every wave until its longest request finishes (short requests burn
-slots as padding), while the continuous engine evicts at each request's
-own budget and refills the slot from the queue.
-
-Useful-token accounting: a request contributes at most its own
-``max_new`` tokens; anything a server generates beyond that is wasted
-work and is NOT counted (this is what penalizes lock-step waves).
+Useful-token accounting: a request contributes its own generated
+tokens (at most its ``max_new``).
 
 ``serving_json()`` is the ``BENCH_serving.json`` payload recorded per
 PR (benchmarks/run.py --json); benchmarks/check_serving_regression.py
-gates CI on it against the checked-in baseline.
+gates CI on it.  Times here are the CPU's, not a device's.
 """
 
 from __future__ import annotations
@@ -26,8 +19,7 @@ import jax
 import numpy as np
 
 #: (prompt_len, max_new) per request — mixed lengths, bimodal budgets
-#: (short lookups interleaved with long generations: the traffic shape
-#: where lock-step waves burn ~half their lane-steps as padding).
+#: (short lookups interleaved with long generations).
 WORKLOAD = (
     (8, 2), (5, 24), (11, 2), (4, 24),
     (7, 2), (9, 24), (6, 2), (10, 24),
@@ -35,14 +27,15 @@ WORKLOAD = (
 
 N_SLOTS = 4
 MAX_LEN = 64
+#: cache rows per page: divides gemma2's 8-row sliding window
+PAGE_SIZE = 8
 SERVE_LEVEL = "q16_16"   # FAST: exercises the quantized-weight cache +
                          # fused SwiGLU decode path under request churn
 
 #: shared-prefix workload: long prompts that all open with the same
 #: PREFIX_LEN tokens (system prompt / few-shot header traffic).  The
 #: paged pool with prefix sharing prefills the header ONCE and attaches
-#: its pages to every later request; the contiguous engine re-runs the
-#: full prompt per request (and retraces per distinct length).
+#: its pages to every later request.
 PREFIX_LEN = 48
 SP_TAILS = ((2, 8), (5, 8), (9, 8), (3, 8), (7, 8), (11, 8),
             (4, 8), (6, 8), (10, 8), (8, 8), (2, 8), (5, 8))
@@ -50,22 +43,20 @@ SP_MAX_LEN = 128
 SP_PAGE = 16
 
 
-def _requests(server=None):
+def _requests(server):
     from repro.runtime.scheduler import Request
 
     rng = np.random.default_rng(7)
-    out = []
-    for i, (plen, max_new) in enumerate(WORKLOAD):
-        rid = server.next_rid() if server is not None else i
-        prompt = rng.integers(1, 100, size=plen).tolist()
-        out.append(Request(rid=rid, prompt=prompt, max_new=max_new, level=SERVE_LEVEL))
-    return out
+    return [
+        Request(rid=server.next_rid(), prompt=rng.integers(1, 100, size=plen).tolist(),
+                max_new=max_new, level=SERVE_LEVEL)
+        for plen, max_new in WORKLOAD
+    ]
 
 
 def _build(cfg_name: str = "gemma2_2b"):
-    """Smoke-family config scaled up so a decode step is compute-bound:
-    the scheduling comparison must measure device time saved, not
-    python dispatch noise (at d_model=64 a step is all dispatch)."""
+    """Smoke-family config scaled up so a decode step is compute-bound,
+    not python dispatch noise (at d_model=64 a step is all dispatch)."""
     import dataclasses
 
     from repro.configs import smoke
@@ -75,31 +66,6 @@ def _build(cfg_name: str = "gemma2_2b"):
     cfg = dataclasses.replace(cfg, name=cfg.name + "-bench", d_model=256, d_ff=1024)
     params = init_params(cfg, jax.random.PRNGKey(0))
     return cfg, params
-
-
-def _static_runner(cfg, params):
-    """Workload closure for the static BatchedServer: FIFO waves of
-    N_SLOTS, each decoded to the wave's LONGEST budget."""
-    from repro.runtime.serve import BatchedServer, ServerConfig
-
-    srv = BatchedServer(
-        cfg, params,
-        ServerConfig(max_batch=N_SLOTS, max_len=MAX_LEN, max_new=1,
-                     start_mode=SERVE_LEVEL),
-    )
-    reqs = _requests()
-    waves = [reqs[i:i + N_SLOTS] for i in range(0, len(reqs), N_SLOTS)]
-
-    def run():
-        useful = 0
-        for wave in waves:
-            srv.scfg.max_new = max(r.max_new for r in wave)  # lock-step cost
-            outs = srv.generate([r.prompt for r in wave])
-            for r, o in zip(wave, outs):
-                useful += min(len(o) - len(r.prompt), r.max_new)
-        return useful
-
-    return run, lambda: {}
 
 
 def _continuous_runner(cfg, params):
@@ -117,7 +83,7 @@ def _continuous_runner(cfg, params):
 
     srv = ContinuousBatchingServer(
         cfg, params,
-        ServingConfig(n_slots=N_SLOTS, max_len=MAX_LEN,
+        ServingConfig(n_slots=N_SLOTS, max_len=MAX_LEN, page_size=PAGE_SIZE,
                       default_level=SERVE_LEVEL,
                       telemetry=TelemetryConfig(enabled=True, trace=True)),
     )
@@ -142,9 +108,9 @@ def _shared_prefix_requests(srv):
     ]
 
 
-def _shared_prefix_runner(cfg, params, paged: bool):
-    """Shared-prefix workload through one persistent continuous server
-    — contiguous pool, or paged pool with prefix sharing on."""
+def _shared_prefix_runner(cfg, params):
+    """Shared-prefix workload through one persistent server with prefix
+    sharing on."""
     from repro.runtime.config import ServingConfig
     from repro.runtime.serve import ContinuousBatchingServer
 
@@ -152,8 +118,7 @@ def _shared_prefix_runner(cfg, params, paged: bool):
         cfg, params,
         ServingConfig(
             n_slots=N_SLOTS, max_len=SP_MAX_LEN, default_level=SERVE_LEVEL,
-            cache="paged" if paged else "contiguous",
-            page_size=SP_PAGE, prefix_sharing=paged,
+            page_size=SP_PAGE, prefix_sharing=True,
         ),
     )
 
@@ -165,27 +130,20 @@ def _shared_prefix_runner(cfg, params, paged: bool):
 
 
 def shared_prefix_json(repeats: int = 3) -> dict:
-    """The ``shared_prefix`` section of the serving payload: paged +
-    prefix-sharing vs contiguous on the long-prompt workload, plus the
-    page-pool capacity numbers (high-water pages vs the slot-contiguous
-    equivalent) that the throughput ratio alone doesn't show."""
+    """The ``shared_prefix`` section of the serving payload: tokens/s
+    with prefix sharing on the long-prompt workload, plus the page-pool
+    capacity numbers (high-water pages vs a slot-contiguous pool's
+    footprint)."""
     cfg, params = _build("deepseek_7b")  # full-context attn: shareable
-    run_c, _ = _shared_prefix_runner(cfg, params, paged=False)
-    run_p, srv_p = _shared_prefix_runner(cfg, params, paged=True)
-    run_c(); run_p()  # warm: compiles + primes the prefix cache
+    run_p, srv_p = _shared_prefix_runner(cfg, params)
+    run_p()  # warm: compiles + primes the prefix cache
 
-    c_walls, p_walls = [], []
+    p_walls = []
     for _ in range(repeats):
-        t0 = time.perf_counter()
-        c_toks = run_c()
-        c_walls.append(time.perf_counter() - t0)
         t0 = time.perf_counter()
         p_toks = run_p()
         p_walls.append(time.perf_counter() - t0)
-    c_wall = sorted(c_walls)[len(c_walls) // 2]
     p_wall = sorted(p_walls)[len(p_walls) // 2]
-    cont_tps = c_toks / c_wall
-    paged_tps = p_toks / p_wall
 
     report = srv_p.cache_ops.report()
     full = report["groups"][f"L{SP_MAX_LEN}"]
@@ -193,9 +151,7 @@ def shared_prefix_json(repeats: int = 3) -> dict:
         "workload": {"prefix_len": PREFIX_LEN, "tails": list(SP_TAILS),
                      "n_slots": N_SLOTS, "max_len": SP_MAX_LEN,
                      "page_size": SP_PAGE},
-        "contiguous_tokens_per_s": cont_tps,
-        "paged_tokens_per_s": paged_tps,
-        "paged_speedup": paged_tps / cont_tps,
+        "paged_tokens_per_s": p_toks / p_wall,
         "prefix_hits": srv_p.stats["prefix_hits"],
         "prefix_tokens_reused": srv_p.stats["prefix_tokens_reused"],
         "prefill_chunks": srv_p.stats["prefill_chunks"],
@@ -210,30 +166,19 @@ def shared_prefix_json(repeats: int = 3) -> dict:
 
 def serving_json(repeats: int = 3, trace_out=None, metrics_out=None) -> dict:
     """``trace_out`` / ``metrics_out``: optional paths; when given, the
-    continuous server's Chrome trace and Prometheus exposition are
-    written there after the timed passes (CI uploads both as
-    artifacts)."""
+    server's Chrome trace and Prometheus exposition are written there
+    after the timed passes (CI uploads both as artifacts)."""
     cfg, params = _build()
-    run_s, _ = _static_runner(cfg, params)
     run_c, srv_c = _continuous_runner(cfg, params)
-    run_s(); run_c()  # warm: pays every compile on both engines
+    run_c()  # warm: pays every compile
 
-    # INTERLEAVED timed passes: shared-host noise hits both servers in
-    # the same window, so the gated speedup ratio stays stable even
-    # when absolute tokens/s swing between invocations.
-    s_walls, c_walls = [], []
+    c_walls = []
     for _ in range(repeats):
-        t0 = time.perf_counter()
-        s_toks = run_s()
-        s_walls.append(time.perf_counter() - t0)
         t0 = time.perf_counter()
         c_toks = run_c()
         c_walls.append(time.perf_counter() - t0)
-    s_wall = sorted(s_walls)[len(s_walls) // 2]
     c_wall = sorted(c_walls)[len(c_walls) // 2]
     stats = dict(srv_c.stats)
-    static_tps = s_toks / s_wall
-    cont_tps = c_toks / c_wall
     if trace_out is not None:
         srv_c.telemetry.write_trace(trace_out)
     if metrics_out is not None:
@@ -244,11 +189,9 @@ def serving_json(repeats: int = 3, trace_out=None, metrics_out=None) -> dict:
         "model": "gemma2_2b-smoke",
         "level": SERVE_LEVEL,
         "workload": {"requests": list(WORKLOAD), "n_slots": N_SLOTS,
-                     "max_len": MAX_LEN},
-        "useful_tokens": {"static": s_toks, "continuous": c_toks},
-        "static_tokens_per_s": static_tps,
-        "continuous_tokens_per_s": cont_tps,
-        "speedup": cont_tps / static_tps,
+                     "max_len": MAX_LEN, "page_size": PAGE_SIZE},
+        "useful_tokens": {"continuous": c_toks},
+        "continuous_tokens_per_s": c_toks / c_wall,
         "continuous_stats": stats,
         "telemetry": srv_c.metrics_snapshot(),
         "shared_prefix": shared_prefix_json(repeats),
@@ -259,15 +202,11 @@ def bench_serving():
     """CSV rows for benchmarks/run.py."""
     p = serving_json()
     return [
-        ("serving.static_tok_s", 0.0,
-         f"tokens_per_s={p['static_tokens_per_s']:.1f},useful={p['useful_tokens']['static']}"),
         ("serving.continuous_tok_s", 0.0,
          f"tokens_per_s={p['continuous_tokens_per_s']:.1f},"
-         f"speedup_vs_static={p['speedup']:.2f},"
          f"decode_steps={p['continuous_stats']['decode_steps']}"),
         ("serving.paged_shared_prefix_tok_s", 0.0,
          f"tokens_per_s={p['shared_prefix']['paged_tokens_per_s']:.1f},"
-         f"speedup_vs_contiguous={p['shared_prefix']['paged_speedup']:.2f},"
          f"prefix_hits={p['shared_prefix']['prefix_hits']},"
          f"capacity_ratio={p['shared_prefix']['memory']['capacity_ratio']:.2f}"),
     ]

@@ -29,7 +29,9 @@ sys.path.insert(0, str(Path(__file__).parent.parent / "src"))
 sys.path.insert(0, str(Path(__file__).parent))
 
 from _gate import GateRow, emit  # noqa: E402
-from bench_serving import MAX_LEN, N_SLOTS, SERVE_LEVEL, _build, _requests  # noqa: E402
+from bench_serving import (  # noqa: E402
+    MAX_LEN, N_SLOTS, PAGE_SIZE, SERVE_LEVEL, _build, _requests,
+)
 
 
 def _runner(cfg, params, enabled: bool):
@@ -39,7 +41,7 @@ def _runner(cfg, params, enabled: bool):
 
     srv = ContinuousBatchingServer(
         cfg, params,
-        ServingConfig(n_slots=N_SLOTS, max_len=MAX_LEN,
+        ServingConfig(n_slots=N_SLOTS, max_len=MAX_LEN, page_size=PAGE_SIZE,
                       default_level=SERVE_LEVEL,
                       telemetry=TelemetryConfig(enabled=enabled, trace=enabled)),
     )

@@ -6,8 +6,9 @@ Run:  PYTHONPATH=src python -m benchmarks.run [--section trig|universal|mul|matm
 ``--json`` additionally records the perf trajectories: writes
 ``BENCH_fused_mlp.json`` (fused/unfused/precise medians at the
 configs/ MLP shapes + smoke-model decode tokens/s),
-``BENCH_serving.json`` (static vs continuous-batching tokens/s on the
-mixed-length serving workload — gated in CI by
+``BENCH_serving.json`` (continuous-batching tokens/s on the
+mixed-length serving workload and prefix-sharing hits on a
+shared-prefix one — gated in CI by
 benchmarks/check_serving_regression.py) AND
 ``BENCH_speculative.json`` (ladder-speculative vs vanilla f32 greedy
 tokens/s — gated in CI by benchmarks/check_speculative_regression.py)

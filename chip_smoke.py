@@ -5,13 +5,12 @@ Every width is as published (d_model 4096, 32 x 128 heads with 32 KV
 heads, d_ff 11008, vocab 102400); only the depth is cut (:data:`LAYERS`).
 Weights are random from a fixed seed.  The run goes through the serving
 entry points a user calls -- ``ContinuousBatchingServer``, its
-scheduler, ``ContiguousCacheOps`` and ``PagedCachePool`` -- in one
+scheduler and its ``PagedCachePool`` with chunked prefill -- in one
 process, in two phases:
 
-1. contiguous cache: one request per slot, prompts of two lengths,
-   ``f32`` and ``q16_16`` requests mixed in one batch;
-2. paged cache with chunked prefill, the same mix plus one speculative
-   request.
+1. one request per slot, prompts of two lengths, ``f32`` and
+   ``q16_16`` requests mixed in one batch;
+2. the same mix plus one speculative request.
 
 Checks, any of which fails the run: every served token lies in
 ``[0, vocab)``; every health signal the per-slot arbiters were fed was
@@ -192,7 +191,6 @@ def serve_phase(name, cfg, params, scfg, specs, log):
           f"{name}: a non-finite logit reached the arbiter")
     report = {
         "phase": name,
-        "cache": scfg.cache,
         "requests": len(reqs),
         "tokens_served": int(sum(len(g) for g in out.values())),
         "build_s": t1 - t0,
@@ -286,19 +284,19 @@ def run_smoke(cfg, *, slots: int, max_len: int, prompt_lens, max_new: int,
     mix = [(prompts[i % len(prompts)], max_new + 4 * i, levels[(i // 2) % 2], False)
            for i in range(slots)]
 
-    contiguous = ServingConfig(n_slots=slots, max_len=max_len, seed=seed)
-    served, rep1 = serve_phase("phase1", cfg, params, contiguous, mix, log)
+    plain = ServingConfig(n_slots=slots, max_len=max_len, seed=seed,
+                          page_size=page_size)
+    served, rep1 = serve_phase("phase1", cfg, params, plain, mix, log)
     log(f"after phase1: live_bytes={live_bytes()} "
         f"bytes_in_use={device_bytes('bytes_in_use')}")
     agree = check_agreement(cfg, params, mix[0][0], served[0], log)
 
-    paged = ServingConfig(
-        n_slots=slots, max_len=max_len, seed=seed, cache="paged",
-        page_size=page_size,
+    speculative = ServingConfig(
+        n_slots=slots, max_len=max_len, seed=seed, page_size=page_size,
         speculative=SpeculativeConfig(k=spec_k, max_len=max_len),
     )
     spec_req = (mix[0][0], mix[0][1], None, True)
-    served2, rep2 = serve_phase("phase2", cfg, params, paged,
+    served2, rep2 = serve_phase("phase2", cfg, params, speculative,
                                 mix[1:] + [spec_req], log)
     spec_tokens = served2[len(mix) - 1]
     # observed, not checked: speculative output is specified to equal

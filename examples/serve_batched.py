@@ -1,37 +1,41 @@
-"""Batched serving example: prefill + lock-step decode with runtime
-precision switching between requests (paper §7.2's hybrid strategy:
-the engine picks the path per workload envelope).
+"""Serving example: continuous batching with per-request precision
+(paper §7.2's hybrid strategy, applied per request: each request names
+the ladder rung it runs at, and one batch mixes rungs).
 
 Run:  PYTHONPATH=src python examples/serve_batched.py
 """
 
 import jax
-import numpy as np
 
 from repro.configs import smoke
-from repro.core.precision import Mode
 from repro.models import init_params
-from repro.runtime.serve import BatchedServer, ServingConfig
+from repro.runtime.scheduler import Request
+from repro.runtime.serve import ContinuousBatchingServer, ServingConfig
 
 
 def main():
     cfg = smoke("gemma2_2b")  # local/global alternating + softcaps
     params = init_params(cfg, jax.random.PRNGKey(0))
-    srv = BatchedServer(cfg, params, ServingConfig(n_slots=4, max_len=64, max_new=12))
+    # page size 4 divides the smoke config's 8-row sliding window
+    srv = ContinuousBatchingServer(cfg, params, ServingConfig(n_slots=4, max_len=64, page_size=4))
 
     prompts = [[1, 2, 3, 4], [9, 8, 7], [5, 5, 5, 5, 5], [2, 4, 6, 8, 10, 12]]
-    print("PRECISE generations:")
-    for i, seq in enumerate(srv.generate(prompts)):
+    print("PRECISE (f32) generations:")
+    for i, seq in enumerate(srv.generate(prompts, max_new=12, level="f32")):
+        print(f"  req{i}: {seq}")
+    print("FAST (q16_16, int8 W8A8) generations:")
+    for i, seq in enumerate(srv.generate(prompts, max_new=12, level="q16_16")):
         print(f"  req{i}: {seq}")
 
-    us = srv.set_mode(Mode.FAST)
-    print(f"\nswitched to FAST (int8 W8A8) in {us:.0f} us (first switch compiles; later switches are O(1))")
-    print("FAST generations:")
-    for i, seq in enumerate(srv.generate(prompts)):
-        print(f"  req{i}: {seq}")
-    us = srv.set_mode(Mode.PRECISE)
-    us = srv.set_mode(Mode.FAST)
-    print(f"steady-state switch latency: {us:.1f} us (paper: 8.09 us on 240 MHz MCU)")
+    # one batch, two rungs: every request decodes at its own level
+    levels = ["f32", "q16_16", "f32", "q16_16"]
+    reqs = [Request(rid=srv.next_rid(), prompt=p, max_new=12, level=lv)
+            for p, lv in zip(prompts, levels)]
+    fins = srv.serve(reqs)
+    print("mixed batch:")
+    for r in reqs:
+        print(f"  req{r.rid} [{r.level}]: {fins[r.rid].tokens}")
+    print(f"stats: {srv.stats}")
 
 
 if __name__ == "__main__":

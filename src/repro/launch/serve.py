@@ -1,34 +1,31 @@
-"""Serving driver: batched generation with runtime precision modes.
+"""Serving launcher: continuous batching with per-request precision.
 
 On the CPU, at smoke widths (``--smoke``; the tests run this way):
-    PYTHONPATH=src python -m repro.launch.serve --arch gemma2_2b --smoke
-    PYTHONPATH=src python -m repro.launch.serve --arch gemma2_2b --smoke --continuous
+    PYTHONPATH=src python -m repro.launch.serve --arch gemma2_2b --smoke --page-size 4
     PYTHONPATH=src python -m repro.launch.serve --arch deepseek_7b --smoke \
-        --continuous --paged --prefix-sharing \
-        --metrics-out metrics.prom --trace-out trace.json
+        --prefix-sharing --metrics-out metrics.prom --trace-out trace.json
 
 On a TPU, at published widths with only the depth cut (``--layers``):
     PYTHONPATH=src python -m repro.launch.serve --arch deepseek_7b --layers 4 \
-        --continuous --slots 8 --max-len 1536 --prompt-lens 256,1024 \
+        --slots 8 --max-len 1536 --prompt-lens 256,1024 \
         --levels f32,q16_16 --max-new 24
 
-``--continuous`` runs the continuous-batching engine (per-request
-precision via ``--levels``) on a mixed-length/mixed-budget workload;
-the default runs the static lock-step ``BatchedServer``.  Both routes
-build ONE :class:`~repro.runtime.config.ServingConfig` — assembled by
-:func:`serving_config_from_args`, which is what
+The launcher runs :class:`~repro.runtime.serve.ContinuousBatchingServer`
+(per-request precision via ``--levels``) on a mixed-length/mixed-budget
+workload.  It builds ONE :class:`~repro.runtime.config.ServingConfig` —
+assembled by :func:`serving_config_from_args`, which is what
 tests/test_serve_cli.py pins: every cache/telemetry flag must round-trip
 into a validated config.  The model comes from
 :func:`model_config_from_args`: the published config of ``--arch``,
 cut to ``--layers`` (never a width), or its smoke reduction under
 ``--smoke``.  Without ``--prompt-lens`` the launcher serves four short
 built-in prompts; with it, one seeded random prompt per length.
-``--continuous --speculative`` serves every
-request through ladder-speculative decoding (draft at ``--draft-level``,
-verify at f32 — output identical to vanilla f32 greedy; watch
-``spec_rounds`` / ``spec_accepted`` in the printed stats).  ``--paged``
-switches the cache pool to fixed-size pages + block tables with chunked
-prefill (``--prefill-chunk`` tokens per fixed-shape segment); add
+``--speculative`` serves every request through ladder-speculative
+decoding (draft at ``--draft-level``, verify at f32 — output identical
+to vanilla f32 greedy; watch ``spec_rounds`` / ``spec_accepted`` in the
+printed stats).  The cache is the paged pool: ``--page-size`` rows a
+page (it must divide every sliding window of the model), chunked
+prefill of ``--prefill-chunk`` tokens per fixed-shape segment, and
 ``--prefix-sharing`` to share full prefix pages between requests
 (full-context attention models only).
 
@@ -85,31 +82,26 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--prompt-lens", default=None,
                     help="comma list of prompt lengths: one seeded random prompt "
                          "per length instead of the four built-in prompts")
-    ap.add_argument("--mode", default="precise", choices=["precise", "fast"])
     ap.add_argument("--max-new", type=int, default=16)
-    ap.add_argument("--continuous", action="store_true",
-                    help="continuous-batching engine instead of the static server")
     ap.add_argument("--slots", type=int, default=2,
-                    help="device batch slots for --continuous")
+                    help="device batch slots")
     ap.add_argument("--levels", default=None,
-                    help="comma list of per-request ladder levels for --continuous "
+                    help="comma list of per-request ladder levels "
                          "(cycled over requests; e.g. 'q16_16,f32')")
     ap.add_argument("--speculative", action="store_true",
-                    help="with --continuous: serve every request in "
-                         "ladder-speculative mode (draft at --draft-level, "
-                         "verify at f32 — output identical to vanilla f32)")
+                    help="serve every request in ladder-speculative mode "
+                         "(draft at --draft-level, verify at f32 — output "
+                         "identical to vanilla f32)")
     ap.add_argument("--draft-level", default="q16_16", choices=["q8_8", "q16_16"],
                     help="draft rung for --speculative")
     ap.add_argument("--spec-k", type=int, default=3,
                     help="draft tokens per speculative round")
-    ap.add_argument("--paged", action="store_true",
-                    help="with --continuous: paged cache pool + chunked prefill")
     ap.add_argument("--page-size", type=int, default=16,
-                    help="cache rows per page for --paged")
+                    help="cache rows per page")
     ap.add_argument("--prefill-chunk", type=int, default=None,
                     help="chunked-prefill segment length (default: page size)")
     ap.add_argument("--prefix-sharing", action="store_true",
-                    help="with --paged: share full prefix pages across requests")
+                    help="share full prefix pages across requests")
     ap.add_argument("--n-pages", type=int, default=None,
                     help="total pages in the full-length pool (default: sized "
                          "to the slot count)")
@@ -172,7 +164,6 @@ def serving_config_from_args(args):
     )
     return ServingConfig(
         n_slots=args.slots, max_len=args.max_len, speculative=spec,
-        cache="paged" if args.paged else "contiguous",
         page_size=args.page_size, prefill_chunk=args.prefill_chunk,
         prefix_sharing=args.prefix_sharing, n_pages=args.n_pages,
         telemetry=telemetry,
@@ -192,11 +183,9 @@ def _write_outputs(srv, args) -> None:
 def main(argv=None):
     args = build_parser().parse_args(argv)
 
-    from repro.core.precision import Mode
     from repro.models import init_params
-    from repro.runtime.config import ServingConfig
     from repro.runtime.scheduler import Request
-    from repro.runtime.serve import BatchedServer, ContinuousBatchingServer
+    from repro.runtime.serve import ContinuousBatchingServer
 
     dev = jax.devices()
     print(f"device: platform={dev[0].platform} kind={dev[0].device_kind} "
@@ -207,41 +196,21 @@ def main(argv=None):
     params = init_params(cfg, jax.random.PRNGKey(0))
     prompts = prompts_from_args(args, cfg.vocab, scfg.seed)
 
-    if args.continuous:
-        srv = ContinuousBatchingServer(cfg, params, scfg)
-        levels = args.levels.split(",") if args.levels else [None]
-        reqs = [
-            Request(rid=srv.next_rid(), prompt=p, max_new=args.max_new + 4 * (i % 2),
-                    level=levels[i % len(levels)],
-                    speculative=args.speculative)
-            for i, p in enumerate(prompts)
-        ]
-        fins = srv.serve(reqs)
-        for r in reqs:
-            f = fins[r.rid]
-            print(f"req{r.rid} [{r.level or 'default'}] ({f.reason}): {f.tokens}")
-        print(f"stats: {srv.stats}")
-        if args.paged:
-            print(f"pages: {srv.cache_ops.report()}")
-        _write_outputs(srv, args)
-        return
-
-    from repro.runtime.telemetry import TelemetryConfig
-
-    srv = BatchedServer(
-        cfg, params,
-        ServingConfig(n_slots=4, max_len=args.max_len, max_new=args.max_new,
-                      default_level=Mode(args.mode),
-                      telemetry=TelemetryConfig(
-                          enabled=bool(args.metrics_out or args.trace_out),
-                          trace=bool(args.trace_out))),
-    )
-    for i, seq in enumerate(srv.generate(prompts)):
-        print(f"req{i}: {seq}")
-    if args.metrics_out or args.trace_out:
-        print("note: --metrics-out/--trace-out apply to --continuous; "
-              "static-server metrics are limited to the weight cache")
-        _write_outputs(srv, args)
+    srv = ContinuousBatchingServer(cfg, params, scfg)
+    levels = args.levels.split(",") if args.levels else [None]
+    reqs = [
+        Request(rid=srv.next_rid(), prompt=p, max_new=args.max_new + 4 * (i % 2),
+                level=levels[i % len(levels)],
+                speculative=args.speculative)
+        for i, p in enumerate(prompts)
+    ]
+    fins = srv.serve(reqs)
+    for r in reqs:
+        f = fins[r.rid]
+        print(f"req{r.rid} [{r.level or 'default'}] ({f.reason}): {f.tokens}")
+    print(f"stats: {srv.stats}")
+    print(f"pages: {srv.cache_ops.report()}")
+    _write_outputs(srv, args)
 
 
 if __name__ == "__main__":

@@ -20,7 +20,6 @@ from repro.models.model import (
     init_params,
     param_specs,
     prefill_step,
-    reset_cache_slot,
     segment_step,
     train_loss,
     truncate_cache_slot,

@@ -36,8 +36,6 @@ __all__ = [
     "mla_forward",
     "init_attn_cache",
     "init_mla_cache",
-    "reset_attn_cache_slot",
-    "reset_mla_cache_slot",
     "truncate_attn_cache_slot",
 ]
 
@@ -265,27 +263,8 @@ def init_attn_cache(
     return out
 
 
-def reset_attn_cache_slot(cache: dict, slot) -> dict:
-    """Reset one batch slot of a KV cache for continuous-batching
-    admission.  Payloads zero; the per-slot position tensor goes back
-    to -1 (unwritten) so the next occupant's decode mask cannot attend
-    to the evicted request's residue.  ``slot`` may be traced."""
-    out = {}
-    for k, v in cache.items():
-        fill = jnp.full(v.shape[1:], -1, v.dtype) if k == "pos" else jnp.zeros(v.shape[1:], v.dtype)
-        out[k] = v.at[slot].set(fill)
-    return out
-
-
-def reset_mla_cache_slot(cache: dict, slot) -> dict:
-    """MLA variant of :func:`reset_attn_cache_slot` (latent ckv/krope
-    payloads + the same -1 position sentinel)."""
-    return reset_attn_cache_slot(cache, slot)
-
-
 def truncate_attn_cache_slot(cache: dict, slot, keep_pos) -> dict:
-    """Truncate-to-position form of :func:`reset_attn_cache_slot`:
-    entries of ONE batch slot whose position is ``>= keep_pos`` go back
+    """Entries of ONE batch slot whose position is ``>= keep_pos`` go back
     to the pristine fill (payloads zero, pos sentinel -1); entries below
     the boundary are untouched BIT-FOR-BIT.  This is the speculative-
     decoding rollback for position-indexed caches (GQA k/v and MLA

@@ -42,7 +42,6 @@ __all__ = [
     "write_rows",
     "segment_step",
     "commit_segment",
-    "reset_cache_slot",
     "write_cache_slot",
     "truncate_cache_slot",
 ]
@@ -121,38 +120,14 @@ def cache_layout(cfg: ModelConfig, max_len: int):
     return out
 
 
-def reset_cache_slot(caches, cfg: ModelConfig, slot):
-    """Reset ONE batch slot of a stacked cache pool (leaves are
-    (n_periods, batch, ...)) to its freshly-initialized state.
-
-    Continuous-batching admission hygiene: an evicted request's KV rows,
-    position sentinels, SSM state and conv history must never leak into
-    the slot's next occupant.  Dispatches to the per-layer resets
-    (:func:`~repro.models.attention.reset_attn_cache_slot` etc., vmapped
-    over the stacked period axis).  ``slot`` may be traced — jit-safe.
-    """
-    from repro.models import attention as attn
-    from repro.models import ssm as ssm_mod
-
-    reset_fn = {"attn": attn.reset_attn_cache_slot,
-                "mla": attn.reset_mla_cache_slot,
-                "mamba": ssm_mod.reset_ssm_cache_slot}
-    out = {}
-    for i, spec in enumerate(cfg.period):
-        fn = reset_fn[spec.kind]
-        out[f"pos{i}"] = jax.vmap(lambda c, fn=fn: fn(c, slot))(caches[f"pos{i}"])
-    return out
-
-
 def truncate_cache_slot(pool, cfg: ModelConfig, slot, keep_pos, ssm_snapshot=None):
-    """Truncate-to-position form of :func:`reset_cache_slot`: roll ONE
-    batch slot of a stacked cache pool back so only entries at positions
-    ``< keep_pos`` survive.  Position-indexed caches (attn/mla) drop the
-    rejected entries in place; SSM caches are cumulative, so their
-    rollback needs ``ssm_snapshot`` — a mapping ``pos{i} ->
-    {"state", "conv"}`` with leaves ``(n_periods, ...)`` holding the
-    slot's cache contents as of ``keep_pos`` (e.g. the per-position
-    states from :func:`segment_step`'s ``seg_aux``).  Raises if the
+    """Roll ONE batch slot of a stacked cache pool back so only entries
+    at positions ``< keep_pos`` survive.  Position-indexed caches
+    (attn/mla) drop the rejected entries in place; SSM caches are
+    cumulative, so their rollback needs ``ssm_snapshot`` — a mapping
+    ``pos{i} -> {"state", "conv"}`` with leaves ``(n_periods, ...)``
+    holding the slot's cache contents as of ``keep_pos`` (e.g. the
+    per-position states from :func:`segment_step`'s ``seg_aux``).  Raises if the
     model has SSM layers and no snapshot is given.  ``slot`` and
     ``keep_pos`` may be traced — jit-safe."""
     from repro.models import attention as attn
@@ -414,16 +389,13 @@ def _scan_rows(params, x, caches, cfg, positions, mode, constrain):
     return jax.lax.scan(body, x, (params["periods"], caches))
 
 
-def write_rows(caches, rows, position, cfg: ModelConfig, mask=None):
+def write_rows(caches, rows, position, cfg: ModelConfig):
     """Write :func:`decode_rows`' rows into a stacked cache (leaves
     ``(n_periods, B, ...)``): lane ``b``'s row of a position-indexed
     cache lands at ``position[b] % L`` (one ``.at[:, b, slot]`` scatter
-    a leaf); SSM leaves take the new state.  ``mask`` (B,) bool: lanes
-    off it keep their cache bit for bit (their scatter is dropped)."""
+    a leaf); SSM leaves take the new state."""
     B = position.shape[0]
     lanes = jnp.arange(B)
-    if mask is not None:
-        lanes = jnp.where(mask, lanes, B)  # out of range -> dropped
     out = {}
     for i, spec in enumerate(cfg.period):
         key = f"pos{i}"
@@ -434,14 +406,8 @@ def write_rows(caches, rows, position, cfg: ModelConfig, mask=None):
                 n: c[n].at[:, lanes, slot].set(r[n].astype(c[n].dtype), mode="drop")
                 for n in c
             }
-        elif mask is None:
-            out[key] = {n: r[n].astype(c[n].dtype) for n in c}
         else:
-            out[key] = {
-                n: jnp.where(mask.reshape((1, -1) + (1,) * (c[n].ndim - 2)),
-                             r[n].astype(c[n].dtype), c[n])
-                for n in c
-            }
+            out[key] = {n: r[n].astype(c[n].dtype) for n in c}
     return out
 
 

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from repro.models.config import ModelConfig
 from repro.models.layers import Spec, attn_norm_spec, pdot, rms_norm
 
-__all__ = ["ssm_specs", "ssm_forward", "init_ssm_cache", "reset_ssm_cache_slot"]
+__all__ = ["ssm_specs", "ssm_forward", "init_ssm_cache"]
 
 
 def ssm_specs(cfg: ModelConfig) -> dict:
@@ -60,16 +60,6 @@ def init_ssm_cache(cfg: ModelConfig, batch: int, dtype=jnp.float32):
     return {
         "state": jnp.zeros((batch, nh, s.d_state, s.head_dim), dtype),
         "conv": jnp.zeros((batch, s.d_conv - 1, conv_dim), dtype),
-    }
-
-
-def reset_ssm_cache_slot(cache: dict, slot) -> dict:
-    """Zero one batch slot of an SSM cache (continuous-batching
-    admission: the recurrent state and conv history of the evicted
-    request must not leak into the next occupant).  ``slot`` may be a
-    traced int32 — jit-safe."""
-    return {
-        k: v.at[slot].set(jnp.zeros(v.shape[1:], v.dtype)) for k, v in cache.items()
     }
 
 

@@ -1,16 +1,15 @@
-"""Paged cache blocks behind a unified ``CacheOps`` surface.
+"""The serving cache: paged blocks with vLLM-style block tables.
 
-The contiguous serving pool reserves ``max_len`` cache rows per slot
-for the slot's whole lifetime — a 5-token lookup holds the same KV
-memory as a 250-token generation.  This module replaces that with
-vLLM-style paging:
+A slot-contiguous pool would reserve ``max_len`` cache rows per slot
+for the slot's whole lifetime — a 5-token lookup would hold the same KV
+memory as a 250-token generation.  The pool here pages instead:
 
 * **pages** — every position-indexed cache tree (attention k/v/pos,
   MLA ckv/krope/pos) is stored as stacked ``(n_periods, n_pages,
   page_size, ...)`` leaves.  Page 0 is the reserved ZERO page (pristine
   fill: payload 0, position sentinel -1) that unallocated block-table
-  entries point at, so a gathered view of an empty slot is exactly the
-  freshly-reset contiguous cache.
+  entries point at, so a gathered view of an empty slot is exactly a
+  freshly initialized cache (``init_caches``).
 * **block tables** — a host-side ``(n_slots, blocks_per_slot)`` int32
   table per page GROUP (caches sharing a length ``L`` share one
   free-list allocator and one table; sliding-window layers form their
@@ -35,35 +34,25 @@ vLLM-style paging:
   boundary depends on when prefill passed it, and SSM state is
   cumulative — neither is a pure function of the token prefix, so
   neither can be shared by content hash.
-
-The api_redesign part: the old ad-hoc helper sprawl
-(``write_cache_slot`` / ``reset_cache_slot`` /
-``reset_{attn,mla,ssm}_cache_slot``) is consolidated behind the
-:class:`CacheOps` protocol (``alloc / write / read / reset / snapshot /
-restore``), implemented by :class:`ContiguousCacheOps` (proven
-bit-identical to the old helpers by tests/test_cachepool.py) and
-:class:`PagedCachePool`.
 """
 
 from __future__ import annotations
 
 import hashlib
 from collections import OrderedDict
-from typing import Dict, List, Optional, Protocol, Sequence, Tuple, runtime_checkable
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models import cache_layout, init_caches, reset_cache_slot, write_cache_slot
+from repro.models import cache_layout, init_caches
 from repro.models.config import ModelConfig
 
 __all__ = [
     "PageAllocator",
     "PrefixCache",
     "token_hash_chain",
-    "CacheOps",
-    "ContiguousCacheOps",
     "PagedCachePool",
 ]
 
@@ -212,86 +201,6 @@ class PrefixCache:
 
 
 # ---------------------------------------------------------------------------
-# the CacheOps protocol + contiguous implementation
-# ---------------------------------------------------------------------------
-
-
-@runtime_checkable
-class CacheOps(Protocol):
-    """The single cache-lifecycle surface both pool layouts implement.
-
-    All methods are FUNCTIONAL over the device state tree returned by
-    :meth:`alloc` (jit/donation friendly); host-side bookkeeping (block
-    tables, refcounts) lives inside the implementation.
-    """
-
-    kind: str
-
-    def alloc(self):
-        """Allocate the device cache state for ``n_slots`` lanes."""
-        ...
-
-    def write(self, state, single, slot: int):
-        """Scatter a single-request cache tree (leaves
-        ``(n_periods, 1, ...)``) into ``slot``."""
-        ...
-
-    def read(self, state, slot: int):
-        """Extract ``slot``'s logical cache as a single-request tree."""
-        ...
-
-    def reset(self, state, slot: int):
-        """Evict ``slot``: restore its logical cache to the pristine
-        fill (payload 0, position sentinel -1, SSM state 0)."""
-        ...
-
-    def snapshot(self, state, slot: int):
-        """Copy of ``slot``'s logical cache (restore token)."""
-        ...
-
-    def restore(self, state, snap, slot: int):
-        """Put a :meth:`snapshot` back into ``slot``."""
-        ...
-
-
-class ContiguousCacheOps:
-    """The legacy slot-contiguous pool behind :class:`CacheOps`.
-
-    Pure delegation to the historical helpers (``init_caches`` /
-    ``write_cache_slot`` / ``reset_cache_slot``) — bit-identity with
-    direct helper calls is pinned by tests/test_cachepool.py, which is
-    what licenses the serving engine to route its admission/eviction
-    writes through this object instead of the helpers."""
-
-    kind = "contiguous"
-
-    def __init__(self, cfg: ModelConfig, n_slots: int, max_len: int,
-                 dtype=jnp.float32):
-        self.cfg = cfg
-        self.n_slots = n_slots
-        self.max_len = max_len
-        self.dtype = dtype
-
-    def alloc(self):
-        return init_caches(self.cfg, self.n_slots, self.max_len, dtype=self.dtype)
-
-    def write(self, state, single, slot):
-        return write_cache_slot(state, single, slot)
-
-    def read(self, state, slot):
-        return jax.tree.map(lambda l: l[:, slot : slot + 1], state)
-
-    def reset(self, state, slot):
-        return reset_cache_slot(state, self.cfg, slot)
-
-    def snapshot(self, state, slot):
-        return jax.tree.map(lambda l: l[:, slot : slot + 1].copy(), state)
-
-    def restore(self, state, snap, slot):
-        return write_cache_slot(state, snap, slot)
-
-
-# ---------------------------------------------------------------------------
 # the paged pool
 # ---------------------------------------------------------------------------
 
@@ -304,7 +213,7 @@ class PagedCachePool:
     * ``state["pages"][key][leaf]`` — ``(n_periods, n_pages, page_size,
       ...)`` for every position-indexed cache ``key``;
     * ``state["slot"][key][leaf]`` — the cumulative SSM leaves,
-      slot-contiguous exactly as in the contiguous pool.
+      ``(n_periods, n_slots, ...)``, one row per slot.
 
     Jit-safe adapters (device tables passed as arguments so table
     edits never retrace): :meth:`device_view`, :meth:`commit_rows`,
@@ -312,8 +221,6 @@ class PagedCachePool:
     :meth:`prepare_admission`, :meth:`ensure_rows`, :meth:`free_slot`,
     :meth:`finish_admission`.
     """
-
-    kind = "paged"
 
     def __init__(self, cfg: ModelConfig, n_slots: int, max_len: int,
                  page_size: int, dtype=jnp.float32, *,
@@ -400,7 +307,7 @@ class PagedCachePool:
         self._m_prefix_entries = registry.gauge(
             "prefix_entries", "prefix-cache entries resident")
 
-        # leaf templates from the contiguous initializer: the paged pool
+        # leaf templates from the model's cache initializer: the pool
         # stores EXACTLY the same leaves, page-major
         single = init_caches(cfg, 1, max_len, dtype=dtype)
         self._templates = {
@@ -511,9 +418,9 @@ class PagedCachePool:
 
     def device_view(self, state, tables):
         """Gather the logical ``(n_periods, B, L, ...)`` cache tree the
-        model steps consume — bit-identical values to the contiguous
-        pool holding the same logical content (unallocated blocks show
-        the zero page's pristine rows)."""
+        model steps consume — bit-identical values to an
+        ``init_caches`` tree holding the same logical content
+        (unallocated blocks show the zero page's pristine rows)."""
         view = {}
         for gk, g in self.groups.items():
             t = tables[gk]  # (B, nb)
@@ -789,12 +696,12 @@ class PagedCachePool:
                 return False
         return True
 
-    # -- CacheOps completeness (host/test paths, eager jnp) ------------------
+    # -- whole-slot host paths (eager jnp) -----------------------------------
 
     def write(self, state, single, slot):
-        """Scatter a fully-populated single-request tree into ``slot``
-        (allocates the slot's whole block span — protocol parity with
-        the contiguous pool's admission write)."""
+        """Scatter a fully-populated single-request tree (leaves
+        ``(n_periods, 1, ...)``) into ``slot``, allocating the slot's
+        whole block span."""
         for gk, g in self.groups.items():
             missing = [b for b in range(g["nb"]) if g["table"][slot, b] == 0]
             state = self._attach_fresh(state, slot, gk, missing)
@@ -806,9 +713,11 @@ class PagedCachePool:
         )
 
     def read(self, state, slot):
+        """``slot``'s logical cache as a single-request tree."""
         return self.slot_view(state, self.slot_tables(slot), jnp.int32(slot))
 
     def reset(self, state, slot):
+        """Evict ``slot``: release its pages and zero its SSM lanes."""
         self.free_slot(slot)
         slot_leaves = {}
         for key in self.slot_keys:
@@ -841,8 +750,8 @@ class PagedCachePool:
 
     def report(self) -> dict:
         """Capacity numbers for the serving benchmark: pages resident /
-        high-water per group, plus the contiguous-equivalent row count
-        the same workload would have reserved."""
+        high-water per group, plus the pages a slot-contiguous pool
+        (every slot's full span) would have reserved."""
         out = {"page_size": self.page_size, "groups": {}}
         for gk, g in self.groups.items():
             a = g["alloc"]

@@ -1,14 +1,10 @@
-"""The unified serving configuration surface.
+"""The serving configuration surface.
 
-Historically the two servers grew their own kwarg sprawls
-(``ServerConfig`` for the static :class:`~repro.runtime.serve.\
-BatchedServer``, ``ContinuousServerConfig`` for the continuous engine)
-plus a third implicit surface of per-call knobs.  :class:`ServingConfig`
-consolidates them: ONE validated dataclass that both servers accept and
-that also carries the cache-layout policy introduced with the paged
-pool (``cache`` / ``page_size`` / ``prefill_chunk`` / ``prefix_sharing``
-/ ``n_pages``).  The old dataclasses survive as deprecation-warned
-shims in :mod:`repro.runtime.serve`.
+:class:`ServingConfig` is ONE validated dataclass that
+:class:`~repro.runtime.serve.ContinuousBatchingServer` builds from: the
+batch shape, sampling, the per-request precision ladder, speculation,
+and the page pool's policy (``page_size`` / ``prefill_chunk`` /
+``prefix_sharing`` / ``n_pages``).
 
 Validation happens eagerly in ``__post_init__`` — a config that
 constructs is a config a server can build from (model-dependent checks
@@ -43,50 +39,38 @@ SERVE_CACHE_DTYPE = jnp.float32
 
 @dataclasses.dataclass
 class ServingConfig:
-    """One config for both servers.
+    """The serving engine's configuration.
 
-    Core (both servers): ``n_slots`` (device lanes / max static batch),
-    ``max_len`` (context window = pool length), ``eos_id``,
-    ``temperature``, ``default_level`` (per-request requests may
-    override on the continuous engine; the static server's single
-    level), ``seed``.
+    Core: ``n_slots`` (device lanes), ``max_len`` (context window =
+    pool length), ``eos_id``, ``temperature``, ``default_level`` (the
+    ladder level of requests that name none), ``seed``,
+    ``health_sync_every``, ``arbiter``, ``speculative`` — see
+    :class:`~repro.runtime.serve.ContinuousBatchingServer`.
 
-    Continuous-engine knobs: ``health_sync_every``, ``arbiter``,
-    ``speculative`` — see :class:`~repro.runtime.serve.\
-    ContinuousBatchingServer`.
-
-    Static-server knob: ``max_new`` (per-wave decode budget; the
-    continuous engine takes budgets per request).
-
-    Cache layout (continuous engine):
-
-    * ``cache="contiguous"`` — the legacy slot-contiguous pool: every
-      slot owns ``max_len`` cache rows for its lifetime.
-    * ``cache="paged"`` — fixed-size pages + free-list block tables
-      (see :mod:`repro.runtime.cachepool`): slots map logical blocks to
-      physical pages, admission runs CHUNKED prefill
-      (``prefill_chunk``-token fixed-shape segments — zero retraces
-      across prompt lengths), and ``prefix_sharing=True`` shares
-      full pages between requests with a common token prefix
-      (copy-on-write, token-hash keyed).
+    Cache: fixed-size pages + free-list block tables (see
+    :mod:`repro.runtime.cachepool`): slots map logical blocks to
+    physical pages, admission runs CHUNKED prefill (``prefill_chunk``-
+    token fixed-shape segments — zero retraces across prompt lengths),
+    and ``prefix_sharing=True`` shares full pages between requests with
+    a common token prefix (copy-on-write, token-hash keyed).
+    ``cache`` names the layout and accepts only ``"paged"``, the one
+    layout there is.
 
     ``page_size`` must divide ``max_len`` (and, checked at server
     build, every sliding-window cache length).  ``prefill_chunk``
-    defaults to ``page_size`` on the paged path; prefix sharing
-    REQUIRES chunk == page_size so page contents are a deterministic
-    function of the token prefix alone (chunk boundaries land on the
-    same global grid regardless of how much prefix was reused).
-    ``n_pages`` overrides the full-length page-pool size (default:
-    2x the contiguous footprint when sharing is on, 1x + headroom
-    otherwise).
+    defaults to ``page_size``; prefix sharing REQUIRES chunk ==
+    page_size so page contents are a deterministic function of the
+    token prefix alone (chunk boundaries land on the same global grid
+    regardless of how much prefix was reused).  ``n_pages`` overrides
+    the full-length page-pool size (default: one full span per slot,
+    twice that when sharing is on, plus the zero page).
     """
 
     n_slots: int = 4
     max_len: int = 256
     eos_id: Optional[int] = None
     temperature: float = 0.0          # 0 = greedy
-    default_level: Any = "f32"        # ladder level name (or Mode alias
-                                      # for the static server)
+    default_level: Any = "f32"        # ladder level name
     seed: int = 0
     #: health-signal sync cadence (decode steps) when NO eos_id is set.
     health_sync_every: int = 8
@@ -95,18 +79,15 @@ class ServingConfig:
     )
     #: enable ladder-speculative decoding for requests that ask for it.
     speculative: Optional[SpeculativeConfig] = None
-    #: static-server per-wave decode budget.
-    max_new: int = 32
-    #: cache layout: "contiguous" (legacy slot rows) | "paged".
-    cache: str = "contiguous"
-    #: physical page length (cache rows per page) on the paged path.
+    #: cache layout: "paged", the only one.
+    cache: str = "paged"
+    #: physical page length (cache rows per page).
     page_size: int = 16
-    #: chunked-prefill segment length; None = page_size on the paged
-    #: path (the contiguous path keeps whole-prompt prefill).
+    #: chunked-prefill segment length; None = page_size.
     prefill_chunk: Optional[int] = None
-    #: share full prefix pages between requests (paged path only;
-    #: requires a model whose caches are all full-context
-    #: position-indexed — no sliding windows, no SSM state).
+    #: share full prefix pages between requests (requires a model whose
+    #: caches are all full-context position-indexed — no sliding
+    #: windows, no SSM state).
     prefix_sharing: bool = False
     #: total pages in the full-length page pool (incl. the reserved
     #: zero page); None = a validated default.
@@ -124,41 +105,34 @@ class ServingConfig:
             raise ValueError("n_slots must be >= 1")
         if self.max_len < 2:
             raise ValueError("max_len must be >= 2")
-        if self.max_new < 1:
-            raise ValueError("max_new must be >= 1")
         if self.health_sync_every < 1:
             raise ValueError("health_sync_every must be >= 1")
-        if self.cache not in ("contiguous", "paged"):
-            raise ValueError(f"cache must be 'contiguous' or 'paged', got {self.cache!r}")
-        if self.cache == "paged":
-            if self.page_size < 1:
-                raise ValueError("page_size must be >= 1")
-            if self.max_len % self.page_size:
-                raise ValueError(
-                    f"page_size {self.page_size} must divide max_len {self.max_len}"
-                )
+        if self.cache != "paged":
+            raise ValueError(
+                f"cache must be 'paged', the paged pool is the only cache layout; "
+                f"got {self.cache!r}"
+            )
+        if self.page_size < 1:
+            raise ValueError("page_size must be >= 1")
+        if self.max_len % self.page_size:
+            raise ValueError(
+                f"page_size {self.page_size} must divide max_len {self.max_len}"
+            )
         if self.prefill_chunk is not None:
-            if self.cache != "paged":
-                raise ValueError("prefill_chunk requires cache='paged'")
             if self.prefill_chunk < 1:
                 raise ValueError("prefill_chunk must be >= 1")
             if self.max_len % self.prefill_chunk:
                 raise ValueError(
                     f"prefill_chunk {self.prefill_chunk} must divide max_len {self.max_len}"
                 )
-        if self.prefix_sharing:
-            if self.cache != "paged":
-                raise ValueError("prefix_sharing requires cache='paged'")
-            if self.resolved_chunk != self.page_size:
-                raise ValueError(
-                    "prefix_sharing requires prefill_chunk == page_size: page "
-                    "contents must be a deterministic function of the token "
-                    "prefix alone (chunk boundaries must land on the page grid "
-                    "regardless of how much prefix was matched)"
-                )
+        if self.prefix_sharing and self.resolved_chunk != self.page_size:
+            raise ValueError(
+                "prefix_sharing requires prefill_chunk == page_size: page "
+                "contents must be a deterministic function of the token "
+                "prefix alone (chunk boundaries must land on the page grid "
+                "regardless of how much prefix was matched)"
+            )
         if self.n_pages is not None:
-            if self.cache != "paged":
-                raise ValueError("n_pages requires cache='paged'")
             # every slot needs its max_len worth of blocks available in
             # the worst case, plus the reserved zero page
             if self.n_pages < self.max_len // self.page_size + 1:
@@ -168,9 +142,6 @@ class ServingConfig:
                 )
 
     @property
-    def resolved_chunk(self) -> Optional[int]:
-        """The effective chunked-prefill segment length (None =
-        whole-prompt prefill, the contiguous path's legacy behavior)."""
-        if self.cache != "paged":
-            return self.prefill_chunk
+    def resolved_chunk(self) -> int:
+        """The effective chunked-prefill segment length."""
         return self.prefill_chunk if self.prefill_chunk is not None else self.page_size

@@ -12,23 +12,22 @@ owns the queue, the slot table and per-request token bookkeeping, and
 decides *which* request occupies *which* slot *when* — but touches no
 device state.  The server (:class:`repro.runtime.serve.\
 ContinuousBatchingServer`) drives it and performs the corresponding
-device work (per-slot prefill scatter, pool decode, cache reset).
+device work (chunked prefill into the slot's pages, pool decode, page
+release).
 
 Termination of a request is any of: EOS sampled (when ``eos_id`` is
 configured), its own ``max_new`` budget exhausted, or the shared
 ``max_len`` context window reached.  Because budgets are per-request,
 short requests free their slots early and the next pending request is
-admitted — the continuous-batching win over the static
-``BatchedServer``, which decodes every lane until the LONGEST request
-in the wave finishes.
+admitted — no lane decodes on until the longest request of a wave
+finishes, as a static lock-step batch would.
 
 Invariants (asserted, and pinned by tests/test_scheduler.py):
 
 * a slot is bound to at most one active request at a time;
 * admission is FIFO over submission order;
 * every submitted request is eventually finished exactly once;
-* a finished request's output = prompt + generated tokens (EOS kept,
-  like the static server).
+* a finished request's output = prompt + generated tokens (EOS kept).
 """
 
 from __future__ import annotations

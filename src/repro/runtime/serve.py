@@ -1,18 +1,11 @@
-"""Serving runtime: static batching + continuous batching on the
+"""Serving runtime: continuous batching over a paged KV pool on the
 precision ladder.
 
-Two servers share the per-level step registrations:
-
-:class:`BatchedServer` (static batching, the original engine): up to
-``max_batch`` prompts are padded to a common length, prefilled
-together, then decoded lock-step until ``max_new`` or EOS.  Every lane
-runs at the server's single current level; switching happens at
-request-boundary safety via the two-phase barrier (``set_level``).
-
-:class:`ContinuousBatchingServer` (the serving engine): a fixed device
-batch of ``n_slots`` lanes over a slot-paged KV/SSM pool allocated
-ONCE at build.  A :class:`~repro.runtime.scheduler.ContinuousScheduler`
-interleaves per-request prefill (admission) with pool decode steps;
+:class:`ContinuousBatchingServer` is the serving engine: a fixed device
+batch of ``n_slots`` lanes over a :class:`~repro.runtime.cachepool.\
+PagedCachePool` allocated ONCE at build.  A
+:class:`~repro.runtime.scheduler.ContinuousScheduler` interleaves
+per-request chunked prefill (admission) with pool decode steps;
 finished requests are evicted and their slots re-filled immediately, so
 short requests never wait for long ones.  Each slot carries its own
 ladder level — per-REQUEST precision — driven by a vectorized
@@ -21,21 +14,6 @@ NaN/amplitude signals, and dispatched through the jit-safe
 ``engine.switched`` traced-index path: mixed-precision batches run with
 ZERO retraces (one compiled pool step per active level per decode
 step, merged by an on-device slot mask).
-
-Migration (``BatchedServer`` -> scheduler engine):
-
-=====================================  =====================================
-static ``BatchedServer``               ``ContinuousBatchingServer``
-=====================================  =====================================
-``generate(prompts)`` lock-step wave   ``serve([Request(...)])`` streaming
-one level for the whole batch          per-request ``Request.level`` +
-                                       arbiter escalation per slot
-padded common-length prefill           exact-length per-request prefill
-(shorter rows see right padding)       (no padding artifacts)
-decode until longest request           per-request ``max_new``; slot freed
-                                       at EOS/budget and refilled
-caches rebuilt per ``generate`` call   slot-paged pool allocated once
-=====================================  =====================================
 
 Precision levels: the ``f32`` rung maps to the model-layer ``"exact"``
 mode (f32 residual stream/matmuls/head — see
@@ -63,27 +41,18 @@ from __future__ import annotations
 
 import dataclasses
 import time
-import warnings
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.arbiter import SlotArbiter
-from repro.core.precision import MathEngine, Mode, PrecisionLevel
-from repro.models import (
-    commit_segment,
-    decode_rows,
-    decode_step,
-    init_caches,
-    prefill_step,
-    segment_step,
-    write_rows,
-)
+from repro.core.precision import MathEngine
+from repro.models import commit_segment, decode_rows, segment_step
 from repro.models.config import ModelConfig
 from repro.models.layers import attach_quantized_weights
-from repro.runtime.cachepool import CacheOps, ContiguousCacheOps, PagedCachePool
+from repro.runtime.cachepool import PagedCachePool
 from repro.runtime.config import (
     SERVE_CACHE_DTYPE,
     SERVE_STEP_LEVELS,
@@ -95,195 +64,10 @@ from repro.runtime.telemetry import Telemetry
 
 __all__ = [
     "ServingConfig",
-    "ServerConfig",
-    "BatchedServer",
-    "ContinuousServerConfig",
     "ContinuousBatchingServer",
     "SERVE_STEP_LEVELS",
     "SERVE_CACHE_DTYPE",
 ]
-
-
-@dataclasses.dataclass
-class ServerConfig:
-    """Deprecated: use :class:`~repro.runtime.config.ServingConfig`.
-
-    The static server's historical kwarg surface (``max_batch`` /
-    ``start_mode``).  Kept as a warning shim; :meth:`to_serving` is the
-    field mapping."""
-
-    max_batch: int = 4
-    max_len: int = 256
-    max_new: int = 32
-    eos_id: Optional[int] = None
-    temperature: float = 0.0          # 0 = greedy
-    start_mode: Any = Mode.PRECISE    # Mode compat alias or ladder level name
-    seed: int = 0
-
-    def __post_init__(self):
-        warnings.warn(
-            "ServerConfig is deprecated; use repro.runtime.ServingConfig "
-            "(max_batch -> n_slots, start_mode -> default_level)",
-            DeprecationWarning, stacklevel=3,
-        )
-
-    def to_serving(self) -> ServingConfig:
-        return ServingConfig(
-            n_slots=self.max_batch, max_len=self.max_len, eos_id=self.eos_id,
-            temperature=self.temperature, default_level=self.start_mode,
-            seed=self.seed, max_new=self.max_new,
-        )
-
-
-class BatchedServer:
-    """Static batching (see module docstring for the migration table to
-    :class:`ContinuousBatchingServer`, which supersedes this for mixed
-    workloads — this class remains the lock-step baseline and the
-    simplest correctness oracle)."""
-
-    def __init__(self, cfg: ModelConfig, params, scfg):
-        if isinstance(scfg, ServerConfig):
-            scfg = scfg.to_serving()
-        if scfg.cache != "contiguous":
-            raise ValueError(
-                "BatchedServer supports cache='contiguous' only; the paged "
-                "pool lives on ContinuousBatchingServer"
-            )
-        self.cfg = cfg
-        self.scfg = scfg
-        self.telemetry = Telemetry(scfg.telemetry)
-        self.engine = MathEngine(scfg.default_level)
-        # the engine's weight-cache counting hooks report through this
-        # server's registry (shows up in metrics_snapshot())
-        self.engine.weight_cache.use_registry(self.telemetry.registry)
-        # quantize-once: every FAST weight gets its int8 payload here,
-        # keyed in the engine's cache; the original float leaves stay
-        # (precise path + re-attachment after invalidate_weights).
-        self.params = attach_quantized_weights(
-            params, self.engine.weight_cache, level="q16_16"
-        )
-        self._build()
-
-    def metrics_snapshot(self) -> dict:
-        """Nested-dict snapshot of every registered metric."""
-        return self.telemetry.registry.snapshot()
-
-    def render_prometheus(self) -> str:
-        return self.telemetry.render_prometheus()
-
-    def _build(self):
-        cfg = self.cfg
-
-        def make_prefill(mode):
-            def fn(params, tokens, caches):
-                return prefill_step(params, tokens, caches, cfg, mode=mode)
-            return jax.jit(fn, donate_argnums=(2,))
-
-        def make_decode(mode):
-            def fn(params, tok, pos, caches):
-                return decode_step(params, tok, pos, caches, cfg, mode=mode)
-            return jax.jit(fn, donate_argnums=(3,))
-
-        self.engine.register(
-            "prefill", **{lv: make_prefill(mode) for lv, mode in SERVE_STEP_LEVELS}
-        )
-        self.engine.register(
-            "decode", **{lv: make_decode(mode) for lv, mode in SERVE_STEP_LEVELS}
-        )
-
-    def set_mode(self, mode: Any) -> float:
-        return self.engine.set_level(mode)
-
-    def set_level(self, level: Any) -> float:
-        return self.engine.set_level(level)
-
-    @property
-    def level(self) -> PrecisionLevel:
-        return self.engine.level
-
-    def _sample(self, logits, key):
-        """Vectorized sampling on device: greedy argmax or one batched
-        ``jax.random.categorical`` — no per-row host loop, no full-vocab
-        logit transfer.  Returns a device (B,) int32."""
-        if self.scfg.temperature <= 0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return jax.random.categorical(
-            key, jnp.asarray(logits, jnp.float32) / self.scfg.temperature, axis=-1
-        ).astype(jnp.int32)
-
-    def generate(self, prompts: List[List[int]]) -> List[List[int]]:
-        """Greedy/temperature generation for up to n_slots prompts."""
-        scfg = self.scfg
-        assert len(prompts) <= scfg.n_slots
-        B = len(prompts)
-        key = jax.random.PRNGKey(scfg.seed)
-
-        # left-align, right-pad to the longest prompt
-        plen = max(len(p) for p in prompts)
-        toks = np.zeros((B, plen), np.int32)
-        for i, p in enumerate(prompts):
-            toks[i, : len(p)] = p
-
-        caches = init_caches(self.cfg, B, scfg.max_len, dtype=SERVE_CACHE_DTYPE)
-        logits, caches = self.engine.call("prefill", self.params, jnp.asarray(toks), caches)
-        # NB (static-batching limitation): prefill returns logits at the
-        # common padded last position, so in a mixed-length batch the
-        # first sampled token of a shorter row conditions on its right
-        # padding.  Same-length batches are exact; mixed-length traffic
-        # belongs on ContinuousBatchingServer (exact-length prefill).
-        key, sub = jax.random.split(key)
-        cur = self._sample(logits, sub)          # device (B,), stays there
-        gen = [cur]
-        pos = jnp.full((B,), plen, jnp.int32)    # device; rows move lock-step
-        eos = scfg.eos_id
-        done = np.zeros((B,), bool)
-
-        for step in range(scfg.max_new - 1):
-            if eos is not None:
-                # the one remaining per-token sync: a (B,) token pull
-                done |= np.asarray(gen[-1]) == eos
-                if done.all():
-                    break
-            if plen + step + 1 >= scfg.max_len:
-                break
-            logits, caches = self.engine.call(
-                "decode", self.params, gen[-1][:, None], pos, caches
-            )
-            key, sub = jax.random.split(key)
-            gen.append(self._sample(logits, sub))
-            pos = pos + 1
-
-        # single bulk device->host transfer after the loop
-        mat = np.stack([np.asarray(g) for g in gen], axis=1)  # (B, T)
-        outs = []
-        for i, p in enumerate(prompts):
-            row = mat[i].tolist()
-            if eos is not None and eos in row:
-                row = row[: row.index(eos) + 1]
-            outs.append(list(p) + row)
-        return outs
-
-
-# ---------------------------------------------------------------------------
-# continuous batching
-# ---------------------------------------------------------------------------
-
-
-class ContinuousServerConfig(ServingConfig):
-    """Deprecated: use :class:`~repro.runtime.config.ServingConfig`.
-
-    Pure alias — every historical field (``n_slots`` ... ``speculative``)
-    is a :class:`ServingConfig` field with the same name, default and
-    position, so existing call sites work unchanged modulo the
-    deprecation warning."""
-
-    def __post_init__(self):
-        warnings.warn(
-            "ContinuousServerConfig is deprecated; use "
-            "repro.runtime.ServingConfig (same field names)",
-            DeprecationWarning, stacklevel=3,
-        )
-        super().__post_init__()
 
 
 class ContinuousBatchingServer:
@@ -291,8 +75,9 @@ class ContinuousBatchingServer:
 
     Device state (allocated once at build):
 
-    * ``pool``  — stacked cache pytree for ``n_slots`` lanes x
-      ``max_len`` (the slot-paged KV/SSM pool);
+    * ``pool``  — the page pool's device state (``cache_ops.alloc()``:
+      pages of the position-indexed caches, slot rows of the SSM
+      state), addressed through host block tables;
     * ``_tok`` / ``_pos`` — (n_slots,) current token / next position.
 
     Host state: the :class:`ContinuousScheduler` (queue + slot table +
@@ -339,22 +124,14 @@ class ContinuousBatchingServer:
         )
         if scfg.health_sync_every < 1:
             raise ValueError("health_sync_every must be >= 1")
-        # the cache pool behind the CacheOps surface: slot-contiguous
-        # rows (legacy) or the paged block pool — allocated once either
-        # way, reused across every request the server ever serves
-        self.paged = scfg.cache == "paged"
-        self.cache_ops: CacheOps
-        if self.paged:
-            self.cache_ops = PagedCachePool(
-                cfg, scfg.n_slots, scfg.max_len, scfg.page_size,
-                dtype=SERVE_CACHE_DTYPE, n_pages=scfg.n_pages,
-                prefix_sharing=scfg.prefix_sharing,
-                registry=self.telemetry.registry,
-            )
-        else:
-            self.cache_ops = ContiguousCacheOps(
-                cfg, scfg.n_slots, scfg.max_len, dtype=SERVE_CACHE_DTYPE
-            )
+        # the paged block pool: allocated once, reused across every
+        # request the server ever serves
+        self.cache_ops = PagedCachePool(
+            cfg, scfg.n_slots, scfg.max_len, scfg.page_size,
+            dtype=SERVE_CACHE_DTYPE, n_pages=scfg.n_pages,
+            prefix_sharing=scfg.prefix_sharing,
+            registry=self.telemetry.registry,
+        )
         self.pool = self.cache_ops.alloc()
         self._tok = jnp.zeros((scfg.n_slots,), jnp.int32)
         self._pos = jnp.zeros((scfg.n_slots,), jnp.int32)
@@ -514,31 +291,29 @@ class ContinuousBatchingServer:
     def metrics_snapshot(self) -> dict:
         """Point-in-time nested dict of every metric (refreshes the
         page-pool occupancy gauges first)."""
-        if self.paged:
-            self.cache_ops.scrape_gauges()
+        self.cache_ops.scrape_gauges()
         self._m_active.set(len(self.scheduler.active_slots()))
         return self.telemetry.registry.snapshot()
 
     def render_prometheus(self) -> str:
         """Prometheus text exposition of :meth:`metrics_snapshot`."""
-        if self.paged:
-            self.cache_ops.scrape_gauges()
+        self.cache_ops.scrape_gauges()
         self._m_active.set(len(self.scheduler.active_slots()))
         return self.telemetry.render_prometheus()
 
     # -- jitted step functions ---------------------------------------------
 
     def _build(self):
+        """The jitted steps.  Every step that touches the cache wraps a
+        level-switched model body between a block-table GATHER (pages
+        -> the logical slot-contiguous view the model steps consume)
+        and a row/page SCATTER of exactly what the step wrote.  Block
+        tables are jit ARGUMENTS — allocation/CoW/sharing change table
+        content, never shapes, so the serving loop stays zero-retrace."""
         cfg = self.cfg
         temperature = self.scfg.temperature
-
-        def make_prefill(mode):
-            def fn(params, tokens, caches):
-                # trace-time side effect: fires when jit (re)traces this
-                # body, never at run time — the retrace detector
-                self._m_retrace.inc(step="prefill")
-                return prefill_step(params, tokens, caches, cfg, mode=mode)
-            return fn
+        pool: PagedCachePool = self.cache_ops
+        C = self.scfg.resolved_chunk
 
         def make_decode(mode):
             # lane_mask zeroes non-member lanes so a pass's input tensor
@@ -554,23 +329,19 @@ class ContinuousBatchingServer:
             return fn
 
         self.engine.register(
-            "prefill", **{lv: make_prefill(m) for lv, m in SERVE_STEP_LEVELS}
-        )
-        self.engine.register(
             "decode", **{lv: make_decode(m) for lv, m in SERVE_STEP_LEVELS}
         )
-        pre_disp, _ = self.engine.switched("prefill", levels=self.level_names)
         dec_disp, _ = self.engine.switched("decode", levels=self.level_names)
 
         def mask_cache_view(caches, mask):
             """Non-member lanes see a PRISTINE cache: zero payloads,
-            pos sentinel -1 (the same fill rule as the per-layer slot
-            resets).  Without this, a masked lane attends to its own
-            live cache (q=0 still averages the cached V rows),
-            re-acquiring nonzero activations that leak into the FAST
-            path's per-tensor activation exponents — the isolation
-            contract would then depend on the neighbor's magnitudes.
-            Fills are constants, so this holds no second pool alive."""
+            pos sentinel -1 (the zero page's fill).  Without this, a
+            masked lane attends to its own live cache (q=0 still
+            averages the cached V rows), re-acquiring nonzero
+            activations that leak into the FAST path's per-tensor
+            activation exponents — the isolation contract would then
+            depend on the neighbor's magnitudes.  Fills are constants,
+            so this holds no second pool alive."""
             def walk(node):
                 out = {}
                 for k, v in node.items():
@@ -581,32 +352,6 @@ class ContinuousBatchingServer:
                         out[k] = jnp.where(m, v, jnp.asarray(-1 if k == "pos" else 0, v.dtype))
                 return out
             return walk(caches)
-
-        # per-request prefill: retraces per prompt LENGTH (exact-length,
-        # no padding artifacts), never per level (traced switch index).
-        # No donation: the zero single-request cache template is
-        # allocated once and reused for every admission.
-        self._prefill = jax.jit(pre_disp)
-        self._single_template = init_caches(
-            cfg, 1, self.scfg.max_len, dtype=SERVE_CACHE_DTYPE
-        )
-
-        def pool_pass(level_idx, params, tok, pos, caches, mask, logits_acc):
-            """One decode pass of the whole pool at one level (the
-            mixed-batch path): non-member lanes are zeroed at the input
-            AND see a pristine cache view, so members compute exactly
-            as if the other levels' slots were empty; cache rows and
-            logits are written only where ``mask`` is set."""
-            self._m_retrace.inc(step="pool_pass")
-            view = mask_cache_view(caches, mask)
-            logits, rows = dec_disp(level_idx, params, tok, pos, view, mask)
-            caches = write_rows(caches, rows, pos, cfg, mask)
-            logits_acc = jnp.where(mask[:, None], logits, logits_acc)
-            return logits_acc, caches
-
-        # NB: logits_acc is NOT donated — the zero accumulator template
-        # is reused across steps and must stay valid.
-        self._pool_pass = jax.jit(pool_pass, donate_argnums=(4,))
 
         def finish(logits, key):
             """Sample + per-slot health: [token, finite, amplitude].
@@ -652,82 +397,11 @@ class ContinuousBatchingServer:
 
         self._step_update = jax.jit(step_update, donate_argnums=(0, 1, 2, 3, 4))
 
-        def tick(level_idx, params, tok, pos, caches, mask, key,
-                 gen_buf, gen_count, health):
-            """Fused single-level decode step: pool pass + sampling +
-            ring/health update in ONE dispatch, composed from the same
-            ``finish``/``step_update``/``write_rows`` bodies the
-            mixed-level path jits separately.  The hot path when all
-            active slots share a level (homogeneous traffic); its
-            masked lanes are only EMPTY slots, whose cache rows the
-            eviction reset already zeroed, so no pristine view is
-            needed here."""
-            self._m_retrace.inc(step="tick")
-            logits, rows = dec_disp(level_idx, params, tok[:, None], pos, caches, mask)
-            caches = write_rows(caches, rows, pos, cfg, mask)
-            new_tok, hv = finish(logits, key)
-            gen_buf, gen_count, tok, pos, health = step_update(
-                gen_buf, gen_count, tok, pos, health, new_tok, hv, mask
-            )
-            return caches, gen_buf, gen_count, tok, pos, health, hv
-
-        self._tick = jax.jit(tick, donate_argnums=(2, 3, 4, 7, 8, 9))
-
-        # speculative per-slot mode: draft dispatch (traced rung index)
-        # + fused f32 verify/commit, plus a ring update that appends a
-        # VARIABLE number of committed tokens per slot in one dispatch.
-        self._spec_draft = self._spec_verify = None
-        if self.scfg.speculative is not None:
-            k = self.scfg.speculative.k
-            self._spec_draft, self._spec_verify, self._draft_levels = (
-                register_spec_steps(self.engine, cfg, k)
-            )
-
-            def spec_update(gen_buf, gen_count, preds, n_commit, mask):
-                B, L = gen_buf.shape
-                rows = jnp.arange(B)
-                for j in range(k + 1):  # static unroll: k+1 masked appends
-                    w = mask & (j < n_commit)
-                    idx = jnp.where(w, gen_count + j, L)
-                    gen_buf = gen_buf.at[rows, idx].set(preds[:, j], mode="drop")
-                return gen_buf, gen_count + n_commit
-
-            self._spec_update = jax.jit(spec_update, donate_argnums=(0, 1))
-
-        # cache lifecycle goes through the CacheOps surface.  The
-        # contiguous ops are pure device functions -> jittable as-is;
-        # the paged ops carry host bookkeeping (tables, refcounts) and
-        # are driven un-jitted with jitted adapters (below).
-        if not self.paged:
-            ops = self.cache_ops
-            self._write = jax.jit(ops.write, donate_argnums=(0,))
-            self._reset = jax.jit(ops.reset, donate_argnums=(0,))
-        else:
-            self._write = self._reset = None
-            self._build_paged(dec_disp, mask_cache_view, finish, step_update)
-        self._zero_logits = jnp.zeros((self.scfg.n_slots, cfg.vocab), jnp.float32)
-        self._health_neutral = jnp.tile(
-            jnp.asarray([1.0, 0.0], jnp.float32), (self.scfg.n_slots, 1)
-        )
-
-    def _build_paged(self, dec_disp, mask_cache_view, finish, step_update):
-        """The paged pool's jitted adapters: every step wraps the same
-        level-switched bodies the contiguous path runs, between a
-        block-table GATHER (pages -> the logical slot-contiguous view
-        the model steps already consume) and a row/page SCATTER of
-        exactly what the step wrote.  Block tables are jit ARGUMENTS —
-        allocation/CoW/sharing change table content, never shapes, so
-        the serving loop stays zero-retrace."""
-        cfg = self.cfg
-        pool: PagedCachePool = self.cache_ops
-        C = self.scfg.resolved_chunk
-
         # chunked prefill: ONE fixed (1, C) segment shape for every
-        # prompt length (the contiguous path's exact-length prefill
-        # retraces per length; this is the tentpole's TTFT fix).  The
-        # tail chunk keeps only its r valid rows: commit_segment rolls
-        # the pad positions' writes back bit-for-bit (same rollback
-        # machinery as speculative verify).
+        # prompt length, so admission never retraces.  The tail chunk
+        # keeps only its r valid rows: commit_segment rolls the pad
+        # positions' writes back bit-for-bit (same rollback machinery
+        # as speculative verify).
         def make_chunk(mode):
             def fn(params, tokens, positions, view, keep_pos, keep_count):
                 # trace-time side effect (the zero-retrace counting hook)
@@ -764,10 +438,12 @@ class ContinuousBatchingServer:
 
         def tick_p(level_idx, params, tok, pos, state, tables, mask, key,
                    gen_buf, gen_count, health):
-            """Paged homogeneous-level decode: gather -> fused step ->
-            scatter the ONE row each active lane adds.  Masked lanes
-            are only empty slots here (zero tables -> pristine gather),
-            mirroring the contiguous ``tick``."""
+            """Fused single-level decode step: gather -> pool pass ->
+            scatter the ONE row each active lane adds -> sampling +
+            ring/health update, in ONE dispatch.  The hot path when all
+            active slots share a level; its masked lanes are only EMPTY
+            slots (zero tables -> pristine gather), so no masked view
+            is needed here."""
             self._m_retrace.inc(step="tick")
             view = pool.device_view(state, tables)
             logits, rows = dec_disp(
@@ -784,10 +460,12 @@ class ContinuousBatchingServer:
 
         def pool_pass_p(level_idx, params, tok, pos, state, tables, mask,
                         logits_acc):
-            """Paged mixed-level pass: other levels' lanes are LIVE in
-            the page pool, so the gathered view is pristine-masked (the
+            """One decode pass of the whole pool at one level (the
+            mixed-batch path): other levels' lanes are LIVE in the page
+            pool, so the gathered view is pristine-masked (the
             isolation contract) before the pass; their rows are dropped
-            at the scatter."""
+            at the scatter, and logits are written only where ``mask``
+            is set."""
             self._m_retrace.inc(step="pool_pass")
             view = mask_cache_view(pool.device_view(state, tables), mask)
             logits, rows = dec_disp(level_idx, params, tok, pos, view, mask)
@@ -795,11 +473,28 @@ class ContinuousBatchingServer:
             logits_acc = jnp.where(mask[:, None], logits, logits_acc)
             return logits_acc, state
 
+        # NB: logits_acc is NOT donated — the zero accumulator template
+        # is reused across steps and must stay valid.
         self._pool_pass_p = jax.jit(pool_pass_p, donate_argnums=(4,))
 
+        # speculative per-slot mode: draft dispatch (traced rung index)
+        # + fused f32 verify/commit over the gathered view, plus a ring
+        # update that appends a VARIABLE number of committed tokens per
+        # slot in one dispatch.
         if self.scfg.speculative is not None:
             k = self.scfg.speculative.k
-            draft_j, verify_j = self._spec_draft, self._spec_verify
+            draft_j, verify_j, _ = register_spec_steps(self.engine, cfg, k)
+
+            def spec_update(gen_buf, gen_count, preds, n_commit, mask):
+                B, L = gen_buf.shape
+                rows = jnp.arange(B)
+                for j in range(k + 1):  # static unroll: k+1 masked appends
+                    w = mask & (j < n_commit)
+                    idx = jnp.where(w, gen_count + j, L)
+                    gen_buf = gen_buf.at[rows, idx].set(preds[:, j], mode="drop")
+                return gen_buf, gen_count + n_commit
+
+            self._spec_update = jax.jit(spec_update, donate_argnums=(0, 1))
 
             def spec_draft_p(ri, params, tok, pos, state, tables, dmask):
                 return draft_j(ri, params, tok, pos,
@@ -824,6 +519,11 @@ class ContinuousBatchingServer:
 
             self._spec_verify_p = jax.jit(spec_verify_p, donate_argnums=(4,))
 
+        self._zero_logits = jnp.zeros((self.scfg.n_slots, cfg.vocab), jnp.float32)
+        self._health_neutral = jnp.tile(
+            jnp.asarray([1.0, 0.0], jnp.float32), (self.scfg.n_slots, 1)
+        )
+
     # -- admission / eviction ----------------------------------------------
 
     def _level_idx(self, req: Request) -> int:
@@ -833,9 +533,8 @@ class ContinuousBatchingServer:
         return self.level_names.index(name)
 
     def _admit(self, slot: int, req: Request) -> None:
-        """Prefill the request at its own level and scatter its caches
-        into the pool slot.  No host pull unless EOS checking needs the
-        first token's value."""
+        """Prefill the request at its own level into the slot's pages.
+        No host pull unless EOS checking needs the first token's value."""
         tel = self.telemetry
         plen = len(req.prompt)
         if req.speculative:
@@ -858,15 +557,7 @@ class ContinuousBatchingServer:
         with tel.span("admit", tid=slot + 1,
                       args={"rid": req.rid, "prompt_len": plen}
                       if tel.on else None):
-            if self.paged:
-                logits = self._prefill_chunked(slot, req.prompt, li)
-            else:
-                logits, single = self._prefill(
-                    jnp.int32(li), self.params,
-                    jnp.asarray([req.prompt], jnp.int32),
-                    self._single_template,
-                )
-                self.pool = self._write(self.pool, single, slot)
+            logits = self._prefill_chunked(slot, req.prompt, li)
             self._m_prefills.inc()
             self._key, sub = jax.random.split(self._key)
             tok, hv = self._finish(logits, sub)
@@ -891,12 +582,10 @@ class ContinuousBatchingServer:
             self._finish_slot(slot, reason)
 
     def _prefill_chunked(self, slot: int, prompt: List[int], li: int):
-        """Paged admission: prefix-match + attach shared pages, then
-        feed the unmatched tail through the fixed-shape chunk step —
-        every admission costs ``ceil(tail / C)`` dispatches of ONE
-        compiled executable regardless of prompt length (the contiguous
-        path compiles per distinct length), and a decode tick can run
-        between chunks of later admissions.  Returns the last-token
+        """Admission: prefix-match + attach shared pages, then feed the
+        unmatched tail through the fixed-shape chunk step — every
+        admission costs ``ceil(tail / C)`` dispatches of ONE compiled
+        executable regardless of prompt length.  Returns the last-token
         logits (1, vocab) for first-token sampling."""
         pool: PagedCachePool = self.cache_ops
         self.pool, matched, chain = pool.prepare_admission(self.pool, slot, prompt)
@@ -939,8 +628,9 @@ class ContinuousBatchingServer:
     def _finish_slot(self, slot: int, reason: str) -> FinishedRequest:
         """Pull the request's generated tokens (the one device->host
         transfer a request ever costs in async mode), record it
-        finished, and reset the slot: zero cache rows (pos sentinel
-        back to -1) so no KV/SSM state leaks into the next occupant."""
+        finished, and release the slot: its pages return to the pool
+        (allocation pristine-fills them) and its SSM lanes are zeroed,
+        so no KV/SSM state leaks into the next occupant."""
         n = self.scheduler.n_generated(slot)
         self._m_syncs.inc(kind="evict")
         toks = np.asarray(self._gen_buf[slot, :n]).tolist()
@@ -953,13 +643,10 @@ class ContinuousBatchingServer:
                 self._m_req_latency.observe(time.perf_counter() - t0)
             self.telemetry.async_end("request", id=fin.rid, tid=slot + 1,
                                      args={"reason": reason, "n_generated": n})
-        if self.paged:
-            # release the slot's page references (shared pages survive in
-            # the prefix cache) and zero its cumulative SSM lanes; page
-            # PAYLOADS are not touched — allocation pristine-fills.
-            self.pool = self.cache_ops.reset(self.pool, slot)
-        else:
-            self.pool = self._reset(self.pool, jnp.int32(slot))
+        # release the slot's page references (shared pages survive in
+        # the prefix cache) and zero its cumulative SSM lanes; page
+        # PAYLOADS are not touched — allocation pristine-fills.
+        self.pool = self.cache_ops.reset(self.pool, slot)
         self._tok = self._tok.at[slot].set(0)
         self._pos = self._pos.at[slot].set(0)
         self._gen_count = self._gen_count.at[slot].set(0)
@@ -983,34 +670,23 @@ class ContinuousBatchingServer:
         tel_on = tel.on
         rungs = self.draft_arbiter.idx
         present = sorted(set(int(v) for v in rungs[spec_now]))
-        tables = self.cache_ops.device_tables() if self.paged else None
+        tables = self.cache_ops.device_tables()
         drafts = None
         with tel.span("draft", args={"rungs": len(present)} if tel_on else None):
             for ri in present:
                 dmask = jnp.asarray(spec_now & (rungs == ri))
-                if self.paged:
-                    part = self._spec_draft_p(
-                        jnp.int32(ri), self.params, self._tok, self._pos,
-                        self.pool, tables, dmask,
-                    )
-                else:
-                    part = self._spec_draft(
-                        jnp.int32(ri), self.params, self._tok, self._pos, self.pool, dmask
-                    )
+                part = self._spec_draft_p(
+                    jnp.int32(ri), self.params, self._tok, self._pos,
+                    self.pool, tables, dmask,
+                )
                 drafts = part if drafts is None else jnp.where(dmask[:, None], part, drafts)
         mask_dev = jnp.asarray(spec_now)
         with tel.span("verify", args={"k": k} if tel_on else None):
-            if self.paged:
-                (preds, n_commit, self.pool, self._tok, self._pos,
-                 finite, amp) = self._spec_verify_p(
-                    self.params, self._tok, self._pos, drafts, self.pool,
-                    tables, mask_dev,
-                )
-            else:
-                (preds, n_commit, self.pool, self._tok, self._pos,
-                 finite, amp) = self._spec_verify(
-                    self.params, self._tok, self._pos, drafts, self.pool, mask_dev
-                )
+            (preds, n_commit, self.pool, self._tok, self._pos,
+             finite, amp) = self._spec_verify_p(
+                self.params, self._tok, self._pos, drafts, self.pool,
+                tables, mask_dev,
+            )
             self._gen_buf, self._gen_count = self._spec_update(
                 self._gen_buf, self._gen_count, preds, n_commit, mask_dev
             )
@@ -1063,7 +739,7 @@ class ContinuousBatchingServer:
         seen = set()
         for r in requests:
             self.scheduler.validate(r)
-            if r.speculative and self._spec_verify is None:
+            if r.speculative and self.scfg.speculative is None:
                 raise ValueError(
                     f"request {r.rid}: speculative=True but the server was "
                     "built without a speculative config"
@@ -1078,26 +754,20 @@ class ContinuousBatchingServer:
         wanted = [r.rid for r in requests]
         k = self.scfg.speculative.k if self.scfg.speculative is not None else 0
         mask_key, mask_dev = None, None  # device occupancy mask, uploaded on membership change
-        can_admit = None
-        if self.paged:
-            # paged capacity predicate: FIFO admission stops while the
-            # head request's worst-case block span exceeds free pages
-            # (running requests release pages as they finish)
-            can_admit = lambda r: self.cache_ops.can_admit(r.prompt)
+        # capacity predicate: FIFO admission stops while the head
+        # request's worst-case block span exceeds free pages (running
+        # requests release pages as they finish)
+        can_admit = lambda r: self.cache_ops.can_admit(r.prompt)
         while self.scheduler.has_work():
-            if can_admit is None:
-                for slot, req in self.scheduler.admit():
-                    self._admit(slot, req)
-            else:
-                # one admission per admit() call: _admit allocates the
-                # request's pages, so the NEXT head's capacity check
-                # must see the decremented free count (approving a
-                # whole batch against one stale count over-commits)
-                while True:
-                    pairs = self.scheduler.admit(can_admit, limit=1)
-                    if not pairs:
-                        break
-                    self._admit(*pairs[0])
+            # one admission per admit() call: _admit allocates the
+            # request's pages, so the NEXT head's capacity check must
+            # see the decremented free count (approving a whole batch
+            # against one stale count over-commits)
+            while True:
+                pairs = self.scheduler.admit(can_admit, limit=1)
+                if not pairs:
+                    break
+                self._admit(*pairs[0])
 
             active = self.scheduler.active_mask()
             if not active.any():
@@ -1107,24 +777,23 @@ class ContinuousBatchingServer:
             # spec lane without segment headroom (pos + k would cross
             # max_len) falls back to a vanilla f32 step this iteration.
             spec_now = np.zeros_like(active)
-            if self._spec_verify is not None:
+            if self.scfg.speculative is not None:
                 for s in np.nonzero(active)[0]:
                     if (self.scheduler.request_at(int(s)).speculative
                             and self.scheduler.position(int(s)) + k < self.scfg.max_len):
                         spec_now[s] = True
             van_now = active & ~spec_now
 
-            if self.paged:
-                # make this step's write targets physically backed:
-                # vanilla lanes write one row at pos, spec lanes up to
-                # k+1 rows — allocate missing blocks (and CoW shared
-                # ones) BEFORE the jitted step reads the tables
-                for s in np.nonzero(active)[0]:
-                    p = self.scheduler.position(int(s))
-                    hi = p + k if spec_now[s] else p
-                    self.pool = self.cache_ops.ensure_rows(
-                        self.pool, int(s), p, min(hi, self.scfg.max_len - 1)
-                    )
+            # make this step's write targets physically backed:
+            # vanilla lanes write one row at pos, spec lanes up to k+1
+            # rows — allocate missing blocks (and CoW shared ones)
+            # BEFORE the jitted step reads the tables
+            for s in np.nonzero(active)[0]:
+                p = self.scheduler.position(int(s))
+                hi = p + k if spec_now[s] else p
+                self.pool = self.cache_ops.ensure_rows(
+                    self.pool, int(s), p, min(hi, self.scfg.max_len - 1)
+                )
 
             if spec_now.any():
                 with self.telemetry.span(
@@ -1144,7 +813,7 @@ class ContinuousBatchingServer:
                     levels = self.arbiter.idx
                     present = sorted(set(int(v) for v in levels[van_now]))
                     self._key, sub = jax.random.split(self._key)
-                    tables = self.cache_ops.device_tables() if self.paged else None
+                    tables = self.cache_ops.device_tables()
                     t1 = time.perf_counter() if tel_on else 0.0
                     if len(present) == 1:
                         # hot path: homogeneous level -> ONE fused dispatch
@@ -1154,20 +823,12 @@ class ContinuousBatchingServer:
                         lv = self.level_names[present[0]]
                         with tel.span("level-pass",
                                       args={"level": lv} if tel_on else None):
-                            if self.paged:
-                                (self.pool, self._gen_buf, self._gen_count, self._tok,
-                                 self._pos, self._health, hv) = self._tick_p(
-                                    jnp.int32(present[0]), self.params, self._tok,
-                                    self._pos, self.pool, tables, mask_dev, sub,
-                                    self._gen_buf, self._gen_count, self._health,
-                                )
-                            else:
-                                (self.pool, self._gen_buf, self._gen_count, self._tok,
-                                 self._pos, self._health, hv) = self._tick(
-                                    jnp.int32(present[0]), self.params, self._tok, self._pos,
-                                    self.pool, mask_dev, sub,
-                                    self._gen_buf, self._gen_count, self._health,
-                                )
+                            (self.pool, self._gen_buf, self._gen_count, self._tok,
+                             self._pos, self._health, hv) = self._tick_p(
+                                jnp.int32(present[0]), self.params, self._tok,
+                                self._pos, self.pool, tables, mask_dev, sub,
+                                self._gen_buf, self._gen_count, self._health,
+                            )
                         self._m_level_passes.inc(level=lv)
                         self._count_attn_rows(van_now)
                     else:
@@ -1180,16 +841,10 @@ class ContinuousBatchingServer:
                             lv = self.level_names[li]
                             with tel.span("level-pass",
                                           args={"level": lv} if tel_on else None):
-                                if self.paged:
-                                    logits, self.pool = self._pool_pass_p(
-                                        jnp.int32(li), self.params, self._tok[:, None],
-                                        self._pos, self.pool, tables, mask, logits,
-                                    )
-                                else:
-                                    logits, self.pool = self._pool_pass(
-                                        jnp.int32(li), self.params, self._tok[:, None], self._pos,
-                                        self.pool, mask, logits,
-                                    )
+                                logits, self.pool = self._pool_pass_p(
+                                    jnp.int32(li), self.params, self._tok[:, None],
+                                    self._pos, self.pool, tables, mask, logits,
+                                )
                             self._m_level_passes.inc(level=lv)
                         tok, hv = self._finish(logits, sub)
                         active_dev = jnp.asarray(van_now)
@@ -1252,8 +907,8 @@ class ContinuousBatchingServer:
     def generate(self, prompts: List[List[int]], max_new: int = 32,
                  level: Optional[str] = None,
                  speculative: bool = False) -> List[List[int]]:
-        """BatchedServer-compatible convenience: serve the prompts and
-        return token lists in input order."""
+        """Convenience: serve the prompts as one batch of requests and
+        return their token lists (prompt + generated) in input order."""
         reqs = [
             Request(rid=self.next_rid(), prompt=list(p), max_new=max_new,
                     level=level, speculative=speculative)

@@ -1,9 +1,8 @@
 """Paged cache pool: allocator invariants (property-based), prefix-hash
-contract, CacheOps bit-identity with the legacy helpers, and
-copy-on-write semantics.
+contract, gather/scatter round trips, and copy-on-write semantics.
 
-The serving-level contracts (paged serving == contiguous/alone serving,
-chunked prefill, sharing on == off) live in tests/test_paged_serving.py;
+The serving-level contracts (batch serving == alone serving, chunked
+prefill, sharing on == off) live in tests/test_paged_serving.py;
 this module pins the host-side machinery underneath them.
 """
 
@@ -14,9 +13,8 @@ import pytest
 
 from _pbt import given, settings, strategies as st
 from repro.configs import smoke
-from repro.models import init_caches, reset_cache_slot, write_cache_slot
+from repro.models import init_caches
 from repro.runtime.cachepool import (
-    ContiguousCacheOps,
     PageAllocator,
     PagedCachePool,
     PrefixCache,
@@ -193,53 +191,6 @@ def test_prefix_cache_refcounts_never_negative(seed):
 
 
 # ---------------------------------------------------------------------------
-# ContiguousCacheOps == the legacy helpers, bit for bit
-# ---------------------------------------------------------------------------
-
-
-def _tree_equal(a, b):
-    ok = True
-    for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
-        ok &= bool((np.asarray(la) == np.asarray(lb)).all())
-    return ok
-
-
-@pytest.mark.parametrize("arch", ["gemma2-2b", "jamba-v0.1-52b"])
-def test_contiguous_ops_bit_identical_to_helpers(arch):
-    """The api_redesign safety proof: routing the server's cache
-    lifecycle through ContiguousCacheOps changes NOTHING — every op
-    produces the exact bits the historical helper calls produced."""
-    cfg = smoke(arch)
-    ops = ContiguousCacheOps(cfg, n_slots=3, max_len=32)
-    key = jax.random.PRNGKey(0)
-
-    pool_ops = ops.alloc()
-    pool_ref = init_caches(cfg, 3, 32, dtype=jnp.float32)
-    assert _tree_equal(pool_ops, pool_ref)
-
-    # a fake "prefilled" single-request tree with recognizable bits
-    single = jax.tree.map(
-        lambda l: jax.random.normal(key, l.shape).astype(l.dtype),
-        init_caches(cfg, 1, 32, dtype=jnp.float32),
-    )
-    pool_ops = ops.write(pool_ops, single, 1)
-    pool_ref = write_cache_slot(pool_ref, single, 1)
-    assert _tree_equal(pool_ops, pool_ref)
-
-    assert _tree_equal(ops.read(pool_ops, 1),
-                       jax.tree.map(lambda l: l[:, 1:2], pool_ref))
-
-    snap = ops.snapshot(pool_ops, 1)
-    pool_ops = ops.reset(pool_ops, 1)
-    pool_ref = reset_cache_slot(pool_ref, cfg, 1)
-    assert _tree_equal(pool_ops, pool_ref)
-
-    pool_ops = ops.restore(pool_ops, snap, 1)
-    pool_ref = write_cache_slot(pool_ref, single, 1)
-    assert _tree_equal(pool_ops, pool_ref)
-
-
-# ---------------------------------------------------------------------------
 # PagedCachePool: gather/scatter + copy-on-write
 # ---------------------------------------------------------------------------
 
@@ -250,9 +201,16 @@ def _mk_pool(arch="deepseek-7b", **kw):
     return cfg, pool, pool.alloc()
 
 
+def _tree_equal(a, b):
+    ok = True
+    for la, lb in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        ok &= bool((np.asarray(la) == np.asarray(lb)).all())
+    return ok
+
+
 def test_paged_empty_view_is_pristine():
     """An unallocated slot's gathered view == a freshly initialized
-    contiguous cache (payload 0, pos sentinel -1) — the zero-page
+    cache (payload 0, pos sentinel -1) — the zero-page
     contract the model steps rely on."""
     cfg, pool, state = _mk_pool()
     view = pool.device_view(state, pool.device_tables())

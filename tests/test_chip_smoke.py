@@ -30,12 +30,14 @@ def test_run_smoke_end_to_end(chip_smoke):
     p1, p2, agree = rep["phase1"], rep["phase2"], rep["agreement"]
     # budgets 4 + 4i per slot, every token served
     assert p1["tokens_served"] == p2["tokens_served"] == 4 + 8 + 12 + 16
-    # both rungs ran in phase 1, through the mixed pass and the fused tick
+    # both rungs ran in phase 1, through the mixed pass and the fused
+    # tick, after chunked prefill, without speculation
     assert set(p1["level_passes_total"]) == {"level=f32", "level=q16_16"}
     assert {"step=pool_pass", "step=tick"} <= set(p1["retrace_total"])
-    assert p1["prefill_chunks_total"] == 0
+    assert p1["prefill_chunks_total"] > 0
+    assert p1["spec_drafted_total"] == 0
     # phase 2: chunked prefill and one speculative request
-    assert p2["cache"] == "paged" and p2["prefill_chunks_total"] > 0
+    assert p2["prefill_chunks_total"] > 0
     assert p2["spec_drafted_total"] > 0
     # on the CPU the f32 rung is f32: decode and prefill re-derivation
     # pick the same token at every position

@@ -163,7 +163,7 @@ def test_paged_tick_writes_no_stacked_cache_view():
     cfg = smoke("deepseek_7b")
     params = init_params(cfg, jax.random.PRNGKey(0))
     srv = ContinuousBatchingServer(
-        cfg, params, ServingConfig(n_slots=2, max_len=MAX_LEN, cache="paged", page_size=4))
+        cfg, params, ServingConfig(n_slots=2, max_len=MAX_LEN, page_size=4))
     pool = srv.cache_ops
     tables = pool.device_tables()
     view = pool.device_view(srv.pool, tables)

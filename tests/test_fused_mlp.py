@@ -327,24 +327,30 @@ def test_decode_step_no_weight_requant(rng, monkeypatch):
     assert calls["weight"] > 0  # the old path requantizes in-graph
 
 
+#: page size of the gemma2 servers below: it divides the smoke config's
+#: 8-row sliding window
+PAGE = 4
+
+
 def test_server_weight_cache_populated_once():
     """Server build quantizes each weight exactly once; generate()
     never grows the count (per-step requantization is gone)."""
     from repro.configs.gemma2_2b import CONFIG
     from repro.models import init_params
     from repro.models.config import smoke_config
-    from repro.runtime.serve import BatchedServer, ServerConfig
+    from repro.runtime.serve import ContinuousBatchingServer, ServingConfig
 
     cfg = smoke_config(CONFIG)
     params = init_params(cfg, jax.random.PRNGKey(2))
-    srv = BatchedServer(
-        cfg, params, ServerConfig(max_batch=1, max_len=32, max_new=4, start_mode="q16_16")
+    srv = ContinuousBatchingServer(
+        cfg, params, ServingConfig(n_slots=1, max_len=32, page_size=PAGE,
+                                   default_level="q16_16")
     )
     cache = srv.engine.weight_cache
     built = cache.quantize_calls
     assert built > 0 and cache.hits == 0
-    srv.generate([[1, 2, 3]])
-    srv.generate([[4, 5, 6]])
+    srv.generate([[1, 2, 3]], max_new=4)
+    srv.generate([[4, 5, 6]], max_new=4)
     assert cache.quantize_calls == built
 
 
@@ -368,15 +374,16 @@ def test_server_greedy_matches_teacher_forcing_fast_level():
     from repro.configs.gemma2_2b import CONFIG
     from repro.models import init_params
     from repro.models.config import smoke_config
-    from repro.runtime.serve import BatchedServer, ServerConfig
+    from repro.runtime.serve import ContinuousBatchingServer, ServingConfig
 
     cfg = smoke_config(CONFIG)
     params = init_params(cfg, jax.random.PRNGKey(3))
     prompt = list(range(1, 8))
-    srv = BatchedServer(
-        cfg, params, ServerConfig(max_batch=1, max_len=64, max_new=4, start_mode="q16_16")
+    srv = ContinuousBatchingServer(
+        cfg, params, ServingConfig(n_slots=1, max_len=64, page_size=PAGE,
+                                   default_level="q16_16")
     )
-    out = srv.generate([prompt])[0]
+    out = srv.generate([prompt], max_new=4)[0]
     seq = stepwise(cfg, params, prompt, 4, "fast")
     assert out == seq, (out, seq)
 
@@ -385,17 +392,19 @@ def test_server_eos_trimming():
     from repro.configs.gemma2_2b import CONFIG
     from repro.models import init_params
     from repro.models.config import smoke_config
-    from repro.runtime.serve import BatchedServer, ServerConfig
+    from repro.runtime.serve import ContinuousBatchingServer, ServingConfig
 
     cfg = smoke_config(CONFIG)
     params = init_params(cfg, jax.random.PRNGKey(4))
-    srv = BatchedServer(cfg, params, ServerConfig(max_batch=2, max_len=32, max_new=6))
-    ref = srv.generate([[1, 2, 3], [3, 2, 1]])
+    srv = ContinuousBatchingServer(
+        cfg, params, ServingConfig(n_slots=2, max_len=32, page_size=PAGE))
+    ref = srv.generate([[1, 2, 3], [3, 2, 1]], max_new=6)
     first_new = ref[0][3]
-    srv2 = BatchedServer(
-        cfg, params, ServerConfig(max_batch=2, max_len=32, max_new=6, eos_id=int(first_new))
+    srv2 = ContinuousBatchingServer(
+        cfg, params,
+        ServingConfig(n_slots=2, max_len=32, page_size=PAGE, eos_id=int(first_new)),
     )
-    out = srv2.generate([[1, 2, 3], [3, 2, 1]])
+    out = srv2.generate([[1, 2, 3], [3, 2, 1]], max_new=6)
     # row 0 stops right at its first token == eos
     assert out[0] == [1, 2, 3, int(first_new)]
     # rows never exceed prompt + max_new, and eos appears at most once at the end
@@ -408,20 +417,25 @@ def test_server_temperature_sampling_on_device():
     from repro.configs.gemma2_2b import CONFIG
     from repro.models import init_params
     from repro.models.config import smoke_config
-    from repro.runtime.serve import BatchedServer, ServerConfig
+    from repro.runtime.serve import ContinuousBatchingServer, ServingConfig
 
     cfg = smoke_config(CONFIG)
     params = init_params(cfg, jax.random.PRNGKey(5))
-    srv = BatchedServer(
-        cfg, params,
-        ServerConfig(max_batch=2, max_len=32, max_new=4, temperature=0.8, seed=7),
-    )
-    outs = srv.generate([[1, 2, 3], [4, 5]])
+
+    def fresh():
+        return ContinuousBatchingServer(
+            cfg, params,
+            ServingConfig(n_slots=2, max_len=32, page_size=PAGE, temperature=0.8,
+                          seed=7),
+        )
+
+    outs = fresh().generate([[1, 2, 3], [4, 5]], max_new=4)
     assert all(len(o) > 0 for o in outs)
     for o in outs:
         assert all(0 <= t < cfg.vocab for t in o)
-    # deterministic under a fixed seed
-    outs2 = srv.generate([[1, 2, 3], [4, 5]])
+    # deterministic under a fixed seed: the sampling key runs on across
+    # serve() calls, so the same seed means a fresh server
+    outs2 = fresh().generate([[1, 2, 3], [4, 5]], max_new=4)
     assert outs == outs2
 
 

@@ -92,7 +92,7 @@ def served():
     server's registry afterwards."""
     shapes = jax.eval_shape(lambda k: init_params(CFG, k), jax.random.PRNGKey(0))
     weights = weights_mod.make_weights(shapes, 2**31 + 15)
-    scfg = ServingConfig(n_slots=2, max_len=MAX_LEN, cache="paged", page_size=8,
+    scfg = ServingConfig(n_slots=2, max_len=MAX_LEN, page_size=8,
                          prefill_chunk=8, eos_id=None, temperature=0.0,
                          default_level="f32", seed=0)
     srv = ContinuousBatchingServer(CFG, weights, scfg)

@@ -17,8 +17,8 @@ from repro.runtime.scheduler import ContinuousScheduler, Request
 from repro.models import init_params
 from repro.runtime.serve import (
     ContinuousBatchingServer,
-    ContinuousServerConfig,
     SERVE_STEP_LEVELS,
+    ServingConfig,
 )
 
 
@@ -335,7 +335,7 @@ def small_server(small_model):
     differences."""
     cfg, params = small_model
     return ContinuousBatchingServer(
-        cfg, params, ContinuousServerConfig(n_slots=2, max_len=64)
+        cfg, params, ServingConfig(n_slots=2, max_len=64)
     )
 
 
@@ -367,41 +367,49 @@ def test_slot_reuse_never_leaks_state(small_model):
     late = [7, 3, 7, 3, 7]
     # churned server: one slot, three requests through it; 'late' last
     srv = ContinuousBatchingServer(
-        cfg, params, ContinuousServerConfig(n_slots=1, max_len=64)
+        cfg, params, ServingConfig(n_slots=1, max_len=64)
     )
     churned = srv.generate([[5, 5, 5, 5, 5, 5], [11, 12, 13], late], max_new=5)[-1]
     fresh = ContinuousBatchingServer(
-        cfg, params, ContinuousServerConfig(n_slots=1, max_len=64)
+        cfg, params, ServingConfig(n_slots=1, max_len=64)
     ).generate([late], max_new=5)[0]
     assert churned == fresh
 
 
-@pytest.mark.parametrize("arch", ["deepseek_7b", "jamba_v01_52b"])
-def test_mixed_levels_identical_to_alone(arch):
+@pytest.mark.parametrize("arch,prompts,levels,max_new", [
+    ("deepseek_7b", [[1, 2, 3, 4, 5, 6], [9, 8, 7, 6]], ["f32", "q16_16"], 4),
+    ("jamba_v01_52b", [[1, 2, 3, 4, 5, 6], [9, 8, 7, 6]], ["f32", "q16_16"], 4),
+    # four lanes, mixed lengths, the rungs interleaved across slots
+    ("deepseek_7b",
+     [[1, 2, 3, 4, 5], [7, 8, 9, 10, 11, 12, 13, 14, 15, 16, 17], [3, 1, 4],
+      [2, 7, 1, 8, 2, 8, 1, 8, 2, 8]],
+     ["q16_16", "f32", "q16_16", "f32"], 5),
+], ids=["deepseek_7b", "jamba_v01_52b", "deepseek_7b-4lanes"])
+def test_mixed_levels_identical_to_alone(arch, prompts, levels, max_new):
     """THE per-request-precision contract: a batch mixing q16_16 and
     f32 slots gives every request exactly the tokens it gets when
     served alone at its level (row-independent lanes + traced-index
-    dispatch; includes the hybrid SSM+attention family)."""
+    dispatch through the pristine-masked mixed-level pass; includes the
+    hybrid SSM+attention family)."""
     cfg = smoke(arch)
     params = init_params(cfg, jax.random.PRNGKey(4))
-    pa, pb = [1, 2, 3, 4, 5, 6], [9, 8, 7, 6]
 
+    # 4-row pages: every prompt spans several, so the masked pass
+    # gathers and scatters across page boundaries
     srv = ContinuousBatchingServer(
-        cfg, params, ContinuousServerConfig(n_slots=2, max_len=64)
+        cfg, params, ServingConfig(n_slots=len(prompts), max_len=64, page_size=4)
     )
-    fins = srv.serve([
-        Request(rid=0, prompt=pa, max_new=4, level="f32"),
-        Request(rid=1, prompt=pb, max_new=4, level="q16_16"),
-    ])
+    reqs = [Request(rid=i, prompt=p, max_new=max_new, level=lv)
+            for i, (p, lv) in enumerate(zip(prompts, levels))]
+    fins = srv.serve(reqs)
     assert srv.stats["level_passes"] == 2 * srv.stats["decode_steps"]  # mixed batch
 
     # served alone: one request at a time through the same compiled
-    # server, the other slot empty
-    alone_a = srv.serve([Request(rid=0, prompt=pa, max_new=4, level="f32")])[0]
-    alone_b = srv.serve([Request(rid=1, prompt=pb, max_new=4, level="q16_16")])[1]
-    assert fins[0].tokens == alone_a.tokens
-    assert fins[1].tokens == alone_b.tokens
-    assert alone_a.tokens != alone_b.tokens  # distinct requests, sanity
+    # server, the other slots empty
+    alone = [srv.serve([r])[r.rid].tokens for r in reqs]
+    for r, a in zip(reqs, alone):
+        assert fins[r.rid].tokens == a, r.rid
+    assert alone[0] != alone[1]  # distinct requests, sanity
 
 
 def test_masked_lane_cache_magnitude_cannot_perturb_members(small_model):
@@ -410,34 +418,51 @@ def test_masked_lane_cache_magnitude_cannot_perturb_members(small_model):
     cache view, a masked lane attended to its own cache (q=0 still
     averages the cached V rows), re-acquired nonzero activations, and
     leaked into the FAST path's per-tensor activation exponents — the
-    isolation contract silently depended on neighbor magnitudes."""
+    isolation contract silently depended on neighbor magnitudes.  Driven
+    through the mixed-level pass the server runs (``_pool_pass_p``), on
+    pages the block tables give slot 1."""
     cfg, params = small_model
     srv = ContinuousBatchingServer(
-        cfg, params, ContinuousServerConfig(n_slots=2, max_len=64)
+        cfg, params, ServingConfig(n_slots=2, max_len=64)
     )
     srv.scheduler.submit(Request(rid=0, prompt=[1, 2, 3, 4, 5], max_new=8, level="q16_16"))
     for slot, req in srv.scheduler.admit():
         srv._admit(slot, req)
+    pool = srv.cache_ops
+    # back every row of slot 1 with a page of its own
+    srv.pool = pool.ensure_rows(srv.pool, 1, 0, srv.scfg.max_len - 1)
+    for g in pool.groups.values():
+        assert (g["table"][1] != 0).all()
+    tables = pool.device_tables()
 
-    def plant(node, value):
-        """Fill slot 1's cache rows with large live-looking content
-        (valid slot positions, huge payloads)."""
-        if isinstance(node, dict):
-            out = {}
-            for k, v in node.items():
-                if k == "pos":  # (n_periods, B, L) -> valid positions 0..L-1
-                    out[k] = v.at[:, 1].set(jnp.arange(v.shape[2], dtype=v.dtype)[None, :])
-                else:
-                    out[k] = plant(v, value)
-            return out
-        return node.at[:, 1].set(jnp.full(node.shape[2:], value, node.dtype))
+    def plant(state, value):
+        """Fill slot 1's pages (and SSM lanes) with large live-looking
+        content (valid slot positions, huge payloads)."""
+        ps = pool.page_size
+        pages = {k: dict(v) for k, v in state["pages"].items()}
+        for g in pool.groups.values():
+            pids = jnp.asarray(g["table"][1])
+            for key in g["keys"]:
+                for name, arr in pages[key].items():
+                    if name == "pos":  # (P, n_pages, ps) -> positions 0..L-1
+                        rows = jnp.arange(g["nb"] * ps, dtype=arr.dtype).reshape(g["nb"], ps)
+                        fill = jnp.broadcast_to(rows, (arr.shape[0],) + rows.shape)
+                    else:
+                        fill = jnp.full((arr.shape[0], g["nb"]) + arr.shape[2:], value, arr.dtype)
+                    pages[key][name] = arr.at[:, pids].set(fill)
+        slot = {key: {n: a.at[:, 1].set(value) for n, a in leaves.items()}
+                for key, leaves in state["slot"].items()}
+        return {"pages": pages, "slot": slot}
 
     mask = jnp.asarray(np.array([True, False]))
     li = jnp.int32(srv.level_names.index("q16_16"))
+    # slot 1 looks like another rung's live request late in its context:
+    # at its position every planted row is one it would attend
+    pos = srv._pos.at[1].set(srv.scfg.max_len - 1)
 
-    def run(pool):
-        logits, _ = srv._pool_pass(
-            li, srv.params, srv._tok[:, None], srv._pos, pool, mask,
+    def run(state):
+        logits, _ = srv._pool_pass_p(
+            li, srv.params, srv._tok[:, None], pos, state, tables, mask,
             srv._zero_logits,
         )
         return np.asarray(logits[0])
@@ -482,7 +507,7 @@ def test_arbiter_escalates_slot_mid_request(small_model):
     cfg, params = small_model
     srv = ContinuousBatchingServer(
         cfg, params,
-        ContinuousServerConfig(
+        ServingConfig(
             n_slots=1, max_len=64, health_sync_every=2,
             default_level="q16_16",
             arbiter=SlotArbiterConfig(
@@ -505,7 +530,7 @@ def test_eos_mode_budgets_and_eviction(small_model):
     with reason 'eos' and the slot is refilled."""
     cfg, params = small_model
     srv = ContinuousBatchingServer(
-        cfg, params, ContinuousServerConfig(n_slots=2, max_len=64, eos_id=127)
+        cfg, params, ServingConfig(n_slots=2, max_len=64, eos_id=127)
     )
     reqs = [Request(rid=srv.next_rid(), prompt=[1, 2, 3], max_new=4),
             Request(rid=srv.next_rid(), prompt=[7, 7], max_new=3)]

@@ -43,17 +43,18 @@ def _cfg(argv):
 
 
 def test_defaults_round_trip():
-    scfg = _cfg(["--continuous"])
+    scfg = _cfg([])
     assert scfg.n_slots == 2
     assert scfg.max_len == MAX_LEN
-    assert scfg.cache == "contiguous"
+    assert scfg.cache == "paged"
+    assert scfg.page_size == 16
     assert scfg.speculative is None
     assert scfg.telemetry.enabled is False
     assert scfg.telemetry.trace is False    # no --trace-out given
 
 
 def test_paged_flags_round_trip():
-    scfg = _cfg(["--continuous", "--paged", "--page-size", "8",
+    scfg = _cfg(["--page-size", "8",
                  "--prefill-chunk", "8", "--prefix-sharing",
                  "--n-pages", "40", "--slots", "3"])
     assert scfg.cache == "paged"
@@ -65,7 +66,7 @@ def test_paged_flags_round_trip():
 
 
 def test_speculative_flags_round_trip():
-    scfg = _cfg(["--continuous", "--speculative", "--spec-k", "4",
+    scfg = _cfg(["--speculative", "--spec-k", "4",
                  "--draft-level", "q8_8"])
     assert scfg.speculative is not None
     assert scfg.speculative.k == 4
@@ -80,7 +81,7 @@ def test_speculative_flags_round_trip():
     (["--metrics-out", "m.prom", "--trace-out", "t.json"], True, True),
 ])
 def test_telemetry_enabled_iff_output_requested(argv, enabled, trace):
-    scfg = _cfg(["--continuous"] + argv)
+    scfg = _cfg(argv)
     assert scfg.telemetry.enabled is enabled
     if enabled:
         assert scfg.telemetry.trace is trace
@@ -90,7 +91,7 @@ def test_invalid_flag_combination_raises():
     # page_size must divide into max_len; the config's own validation
     # fires through the CLI path, not just direct construction
     with pytest.raises(ValueError):
-        _cfg(["--continuous", "--paged", "--page-size", "1000"])
+        _cfg(["--page-size", "1000"])
 
 
 def _args(argv):
@@ -98,8 +99,7 @@ def _args(argv):
 
 
 def test_max_len_round_trips():
-    scfg = serving_config_from_args(_args(["--continuous", "--speculative",
-                                           "--max-len", "2048"]))
+    scfg = serving_config_from_args(_args(["--speculative", "--max-len", "2048"]))
     assert scfg.max_len == 2048
     assert scfg.speculative.max_len == 2048
 
@@ -146,12 +146,11 @@ def test_main_end_to_end_writes_artifacts(tmp_path, capsys, monkeypatch,
     metrics = tmp_path / "metrics.prom"
     trace = tmp_path / "trace.json"
     # page size 4: it must divide gemma2's 8-row sliding window too
-    main(["--arch", "gemma2_2b", "--smoke", "--continuous", "--paged",
-          "--page-size", "4", "--max-new", "2",
+    main(["--arch", "gemma2_2b", "--smoke", "--page-size", "4", "--max-new", "2",
           "--metrics-out", str(metrics), "--trace-out", str(trace)])
 
     out = capsys.readouterr().out
-    assert "req" in out and "stats:" in out
+    assert "req" in out and "stats:" in out and "pages:" in out
     assert "device: platform=cpu" in out
 
     text = metrics.read_text()

@@ -19,7 +19,7 @@ import pytest
 
 from repro.models import init_params
 from repro.runtime.scheduler import Request
-from repro.runtime.serve import ContinuousBatchingServer, ContinuousServerConfig
+from repro.runtime.serve import ContinuousBatchingServer, ServingConfig
 from repro.runtime.speculative import (
     SPEC_DRAFT_LEVELS,
     SpeculativeConfig,
@@ -93,6 +93,10 @@ def test_generate_rejects_insufficient_headroom():
 # ---------------------------------------------------------------------------
 
 
+#: page size of the servers below: it divides gemma2's 8-row window
+PAGE = 4
+
+
 @pytest.fixture(scope="module")
 def spec_server_model():
     cfg = family_config("gemma2_2b")
@@ -108,13 +112,13 @@ def test_server_speculative_matches_vanilla_f32_serving(spec_server_model):
     prompts = make_prompts(cfg.vocab, 7) + make_prompts(cfg.vocab, 8)[:2]
 
     ref = ContinuousBatchingServer(
-        cfg, params, ContinuousServerConfig(n_slots=3, max_len=64)
+        cfg, params, ServingConfig(n_slots=3, max_len=64, page_size=PAGE)
     ).generate(prompts, max_new=12, level="f32")
 
     srv = ContinuousBatchingServer(
         cfg, params,
-        ContinuousServerConfig(
-            n_slots=3, max_len=64,
+        ServingConfig(
+            n_slots=3, max_len=64, page_size=PAGE,
             speculative=SpeculativeConfig(k=3, draft_level="q8_8", max_len=64),
         ),
     )
@@ -130,13 +134,13 @@ def test_server_mixed_spec_and_vanilla_traffic(spec_server_model):
     cfg, params = spec_server_model
     prompts = make_prompts(cfg.vocab, 9)
     ref = ContinuousBatchingServer(
-        cfg, params, ContinuousServerConfig(n_slots=2, max_len=64)
+        cfg, params, ServingConfig(n_slots=2, max_len=64, page_size=PAGE)
     ).generate(prompts, max_new=8, level="f32")
 
     srv = ContinuousBatchingServer(
         cfg, params,
-        ContinuousServerConfig(
-            n_slots=2, max_len=64,
+        ServingConfig(
+            n_slots=2, max_len=64, page_size=PAGE,
             speculative=SpeculativeConfig(k=3, draft_level="q8_8", max_len=64),
         ),
     )
@@ -157,7 +161,7 @@ def test_server_mixed_spec_and_vanilla_traffic(spec_server_model):
 def test_server_rejects_spec_request_without_spec_config(spec_server_model):
     cfg, params = spec_server_model
     srv = ContinuousBatchingServer(
-        cfg, params, ContinuousServerConfig(n_slots=1, max_len=64)
+        cfg, params, ServingConfig(n_slots=1, max_len=64, page_size=PAGE)
     )
     with pytest.raises(ValueError, match="speculative"):
         srv.serve([Request(rid=0, prompt=[1, 2], max_new=2, speculative=True)])
@@ -173,8 +177,8 @@ def test_server_low_acceptance_escalates_draft_rung(spec_server_model):
 
     srv = ContinuousBatchingServer(
         cfg, params,
-        ContinuousServerConfig(
-            n_slots=1, max_len=64,
+        ServingConfig(
+            n_slots=1, max_len=64, page_size=PAGE,
             speculative=SpeculativeConfig(k=3, draft_level="q8_8", max_len=64),
             arbiter=SlotArbiterConfig(
                 n_levels=2, accept_threshold=1.01,  # every round is "low"

@@ -1,6 +1,6 @@
 """Substrate integration tests: data determinism, checkpoint
 atomicity/restart, trainer e2e (loss decreases, failure injection,
-arbiter-driven precision switching), batched serving consistency."""
+arbiter-driven precision switching), serving consistency."""
 
 import numpy as np
 import jax
@@ -13,7 +13,7 @@ from repro.configs import smoke
 from repro.core.precision import Mode
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.models import decode_step, init_caches, init_params, train_loss
-from repro.runtime.serve import BatchedServer, ServerConfig
+from repro.runtime.serve import ContinuousBatchingServer, ServingConfig
 from repro.runtime.train_loop import InjectedFailure, Trainer, TrainerConfig
 
 
@@ -143,8 +143,8 @@ def test_serving_matches_teacher_forcing():
     cfg = smoke("deepseek_7b")
     params = init_params(cfg, jax.random.PRNGKey(3))
     prompt = list(range(1, 9))
-    srv = BatchedServer(cfg, params, ServerConfig(max_batch=1, max_len=64, max_new=6))
-    out = srv.generate([prompt])[0]
+    srv = ContinuousBatchingServer(cfg, params, ServingConfig(n_slots=1, max_len=64))
+    out = srv.generate([prompt], max_new=6, level="f32")[0]
 
     # teacher-forced reference: repeatedly run prefill on the growing
     # sequence (no cache reuse) and take argmax
@@ -169,12 +169,14 @@ def test_serving_matches_teacher_forcing():
 )
 def test_serving_decode_consistency_all_families(arch):
     """Same check across attention variants (SWA rolling cache,
-    local-global, MoE, SSD recurrence, hybrid, MLA absorbed decode)."""
+    local-global, MoE, SSD recurrence, hybrid, MLA absorbed decode).
+    Page size 8 divides the smoke configs' 8-row sliding windows."""
     cfg = smoke(arch)
     params = init_params(cfg, jax.random.PRNGKey(4))
     prompt = list(range(2, 12))
-    srv = BatchedServer(cfg, params, ServerConfig(max_batch=1, max_len=64, max_new=4))
-    out = srv.generate([prompt])[0]
+    srv = ContinuousBatchingServer(
+        cfg, params, ServingConfig(n_slots=1, max_len=64, page_size=8))
+    out = srv.generate([prompt], max_new=4, level="f32")[0]
 
     seq = teacher_forced(cfg, params, prompt, 4, "exact")
     assert out == seq, (arch, out, seq)
@@ -194,16 +196,3 @@ def test_exact_rung_matmuls_traced_at_highest(mode, highest):
     dots = [line for line in text.splitlines() if "dot_general" in line]
     assert dots
     assert [("HIGHEST" in line) for line in dots] == [highest] * len(dots)
-
-
-def test_server_mode_switch_o1():
-    cfg = smoke("deepseek_7b")
-    params = init_params(cfg, jax.random.PRNGKey(5))
-    srv = BatchedServer(cfg, params, ServerConfig(max_batch=2, max_len=32, max_new=2))
-    srv.generate([[1, 2, 3], [4, 5, 6, 7]])  # warm precise
-    srv.set_mode(Mode.FAST)
-    out = srv.generate([[1, 2, 3], [4, 5, 6, 7]])  # compiles fast path once
-    srv.set_mode(Mode.PRECISE)
-    lat = srv.set_mode(Mode.FAST)  # now both warm: O(1)
-    assert lat < 5e4, lat
-    assert len(out) == 2 and all(len(o) > 3 for o in out)

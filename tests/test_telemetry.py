@@ -189,7 +189,7 @@ def test_span_tree_reconciles_paged_mixed_workload():
     cfg, params = _model()
     srv = ContinuousBatchingServer(
         cfg, params,
-        ServingConfig(n_slots=2, max_len=MAX_LEN, cache="paged", page_size=4,
+        ServingConfig(n_slots=2, max_len=MAX_LEN, page_size=4,
                       telemetry=TelemetryConfig(enabled=True, trace=True)),
     )
     reqs = _requests(srv)
@@ -223,7 +223,7 @@ def test_span_tree_reconciles_speculative_workload():
     cfg, params = _model()
     srv = ContinuousBatchingServer(
         cfg, params,
-        ServingConfig(n_slots=2, max_len=MAX_LEN,
+        ServingConfig(n_slots=2, max_len=MAX_LEN, page_size=4,
                       speculative=SpeculativeConfig(k=2, max_len=MAX_LEN),
                       telemetry=TelemetryConfig(enabled=True, trace=True)),
     )
@@ -252,7 +252,7 @@ def test_trace_export_is_valid_chrome_trace(tmp_path):
     cfg, params = _model()
     srv = ContinuousBatchingServer(
         cfg, params,
-        ServingConfig(n_slots=2, max_len=MAX_LEN, cache="paged", page_size=4,
+        ServingConfig(n_slots=2, max_len=MAX_LEN, page_size=4,
                       telemetry=TelemetryConfig(enabled=True, trace=True)),
     )
     srv.serve(_requests(srv))
@@ -292,8 +292,7 @@ def test_disabled_telemetry_is_inert():
     def serve_with(enabled):
         srv = ContinuousBatchingServer(
             cfg, params,
-            ServingConfig(n_slots=2, max_len=MAX_LEN, cache="paged",
-                          page_size=4,
+            ServingConfig(n_slots=2, max_len=MAX_LEN, page_size=4,
                           telemetry=TelemetryConfig(enabled=enabled,
                                                     trace=enabled)),
         )
@@ -332,7 +331,7 @@ def test_retrace_counter_reproduces_chunk_trace_contract():
     cfg, params = _model()
     srv = ContinuousBatchingServer(
         cfg, params,
-        ServingConfig(n_slots=2, max_len=MAX_LEN, cache="paged", page_size=4,
+        ServingConfig(n_slots=2, max_len=MAX_LEN, page_size=4,
                       prefill_chunk=4),
     )
     srv.serve(_requests(srv))  # warmup: one chunk trace per ladder level
@@ -353,7 +352,7 @@ def test_retrace_counter_reproduces_chunk_trace_contract():
 def test_weight_cache_aliases_delegate_to_registry():
     cfg, params = _model()
     srv = ContinuousBatchingServer(
-        cfg, params, ServingConfig(n_slots=2, max_len=MAX_LEN))
+        cfg, params, ServingConfig(n_slots=2, max_len=MAX_LEN, page_size=4))
     srv.serve(_requests(srv))
     wc = srv.engine.weight_cache
     snap = srv.metrics_snapshot()
